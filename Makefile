@@ -77,6 +77,7 @@ corrupt-drill:   ## silent-corruption drill: every injection detected or masked
 	$(PY) -m repro.launch.graph_serve --smoke --corrupt --alg sssp
 
 bench-dist:      ## multi-device column (8 forced host devices, quick scale)
+	XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	$(PY) benchmarks/superstep_bench.py --quick --distributed --devices 8 \
 	  --out BENCH_superstep_dist.json
 

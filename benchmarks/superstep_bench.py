@@ -76,6 +76,7 @@ sys.path.insert(0, str(_ROOT))
 sys.path.insert(0, str(_ROOT / "src"))
 
 from benchmarks.common import timeit  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import graph as G  # noqa: E402
 from repro.core import partition as PT  # noqa: E402
 from repro.core.bsp import BSPEngine  # noqa: E402
@@ -828,31 +829,16 @@ def main(argv=None) -> int:
     if args.quick:
         args.scales = [min(args.scales)]
 
+    enable_compile_cache()
     if args.distributed and len(jax.devices()) < args.devices:
-        # Re-exec with the forced host device count (it must be set before
-        # the jax runtime initializes, so a fresh subprocess is the only
-        # reliable way from an already-imported process).  The sentinel env
-        # var prevents unbounded recursion when the flag cannot take effect
-        # (e.g. a GPU/TPU backend ignores forced *host* devices).
-        import os
-        import subprocess
-        if os.environ.get("_SUPERSTEP_BENCH_REEXEC"):
-            print(f"--distributed needs >= {args.devices} devices but the "
-                  f"re-exec still sees {len(jax.devices())} "
-                  f"({jax.default_backend()} backend); forced host devices "
-                  f"only apply to CPU — run with fewer --devices or on CPU",
-                  file=sys.stderr)
-            return 2
-        env = dict(
-            os.environ,
-            _SUPERSTEP_BENCH_REEXEC="1",
-            XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
-                       f" --xla_force_host_platform_device_count="
-                       f"{args.devices}").strip())
-        r = subprocess.run([sys.executable, __file__]
-                           + list(argv if argv is not None else sys.argv[1:]),
-                           env=env)
-        return r.returncode
+        # Forced host devices must be set before JAX starts, by the caller's
+        # environment (see the bench-dist Makefile target); this process
+        # already holds its devices, so it never re-executes itself.
+        print(f"--distributed needs >= {args.devices} devices but JAX sees "
+              f"{len(jax.devices())} ({jax.default_backend()}); on CPU run "
+              f"with XLA_FLAGS=--xla_force_host_platform_device_count="
+              f"{args.devices}, or pass fewer --devices", file=sys.stderr)
+        return 2
 
     results = []
     failures = []

@@ -46,7 +46,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.runtime import chaos
 from repro.core.partition import (BlockMetadata, EdgeArrays, PartitionedGraph,
                                   build_block_metadata)
@@ -185,11 +184,9 @@ class _Dims:
 class FusedConfig:
     """Static geometry of one direction's fused compute phase."""
 
-    span: int            # lane-aligned block span bound (measured)
+    span: int            # lane-aligned distinct-segment bound per block
     block_e: int
-    v_pad: int           # v_max rounded up to gather_chunk
     max_span: int = 4096
-    gather_chunk: int = 256
     interpret: Optional[bool] = None
 
 
@@ -581,7 +578,7 @@ def _superstep_hybrid_dist(program: VertexProgram, shd, arrs: dict,
                                 axis=1)
         outbox = outbox_reduce_op(
             x_ext, arrs["b_src"][0], arrs["b_local"][0], arrs["b_mask"][0],
-            arrs["b_base"][0], arrs.get("b_weight", [None])[0],
+            arrs["b_ids"][0], arrs.get("b_weight", [None])[0],
             num_slots=shd.num_slots, combine=program.combine,
             weight_op=spec.weight_op if spec.use_weight else None,
             span=shd.b_span, block_e=shd.b_block,
@@ -670,9 +667,6 @@ def _compute_fused(dims: _Dims, program: VertexProgram, edges: dict,
     pl = edges["src"].shape[0]
     vstate = jnp.stack([state[k].astype(jnp.float32) for k in spec.gather],
                        axis=2)                            # [Q, Pl, K, v_max]
-    pad = cfg.v_pad - vstate.shape[3]
-    if pad:
-        vstate = jnp.pad(vstate, ((0, 0), (0, 0), (0, 0), (0, pad)))
     q = vstate.shape[0]
     cols = [jnp.broadcast_to(step.astype(jnp.float32), (q, pl))]
     cols += [state[k].astype(jnp.float32) for k in spec.consts]
@@ -686,10 +680,10 @@ def _compute_fused(dims: _Dims, program: VertexProgram, edges: dict,
     weight = edges.get("weight_blk") if spec.use_weight else None
     return fused_superstep_op(
         msg_fn, vstate, weight, scal, edges["blk_src"], edges["blk_local"],
-        edges["blk_mask"], edges["blk_base"], edges["dst_ext"],
+        edges["blk_mask"], edges["blk_ids"], edges["dst_ext"],
         num_segments=dims.seg, combine=program.combine, span=cfg.span,
         block_e=cfg.block_e, max_span=cfg.max_span,
-        gather_chunk=cfg.gather_chunk, interpret=cfg.interpret)
+        interpret=cfg.interpret)
 
 
 def _superstep(dims: _Dims, program: VertexProgram, edges: dict,
@@ -872,7 +866,7 @@ def _edges_dict(ea: EdgeArrays, blk: Optional[BlockMetadata] = None) -> dict:
         d["blk_src"] = jnp.asarray(blk.src)
         d["blk_local"] = jnp.asarray(blk.local)
         d["blk_mask"] = jnp.asarray(blk.mask)
-        d["blk_base"] = jnp.asarray(blk.base)
+        d["blk_ids"] = jnp.asarray(blk.ids)
         if blk.weight is not None:
             d["weight_blk"] = jnp.asarray(blk.weight)
     return d
@@ -1460,7 +1454,7 @@ class BSPEngine:
 
     def __init__(self, pg, *, backend: Optional[str] = None,
                  fused: bool = False, block_e: int = 1024,
-                 max_span: int = 4096, gather_chunk: int = 256,
+                 max_span: int = 4096,
                  interpret: Optional[bool] = None,
                  hybrid_k_dense: Optional[int] = None,
                  pull_threshold: Optional[float] = None,
@@ -1483,7 +1477,6 @@ class BSPEngine:
         self.interpret = interpret
         self._block_e = block_e
         self._max_span = max_span
-        self._gather_chunk = gather_chunk
         self._hybrid_k_dense = hybrid_k_dense
         # None → fit the push/pull crossover from the perf model
         # (perf_model.fit_pull_threshold, per backend / per shard); a float
@@ -1534,15 +1527,15 @@ class BSPEngine:
             # Instance-level dispatch: the class attributes stay the jitted
             # static-path methods (their compile-cache introspection is part
             # of the serving contract); a dynamic engine shadows them.
-            self._run_batched = self._run_batched_dyn
-            self._run_fixed_batched = self._run_fixed_batched_dyn
+            self._converge = self._run_batched_dyn
+            self._fixed = self._run_fixed_batched_dyn
         if self.tier_plan is not None:
             # Tiered shadows go on *after* the dynamic ones so tiered
             # dispatch wins; the tiered loop folds the dynamic payload in
             # itself (hot rows sliced on device, cold tombstones/deltas
             # streamed with their partitions' windows).
-            self._run_batched = self._run_batched_tiered
-            self._run_fixed_batched = self._run_fixed_batched_tiered
+            self._converge = self._run_batched_tiered
+            self._fixed = self._run_fixed_batched_tiered
 
     @property
     def pg(self) -> PartitionedGraph:
@@ -1559,7 +1552,7 @@ class BSPEngine:
         hybrid plan/caches).  Construction and post-compaction rebinds both
         land here."""
         self._pg = pg
-        block_e, gather_chunk = self._block_e, self._gather_chunk
+        block_e = self._block_e
         self.dims = _Dims(pg.num_parts, pg.v_max, pg.fwd.e_max, pg.fwd.o_max)
         self._fwd_blk = self._rev_blk = None
         if self.fused:
@@ -1573,10 +1566,8 @@ class BSPEngine:
         def _cfg(blk):
             if blk is None:
                 return None
-            v_pad = -(-pg.v_max // gather_chunk) * gather_chunk
             return FusedConfig(span=blk.span, block_e=blk.block_e,
-                               v_pad=v_pad, max_span=self._max_span,
-                               gather_chunk=gather_chunk,
+                               max_span=self._max_span,
                                interpret=self.interpret)
 
         self._fwd_cfg = _cfg(self._fwd_blk)
@@ -1649,7 +1640,7 @@ class BSPEngine:
                     d["blk_src"] = jnp.asarray(blk.src[hot])
                     d["blk_local"] = jnp.asarray(blk.local[hot])
                     d["blk_mask"] = jnp.asarray(blk.mask[hot])
-                    d["blk_base"] = jnp.asarray(blk.base[hot])
+                    d["blk_ids"] = jnp.asarray(blk.ids[hot])
                     if blk.weight is not None:
                         d["weight_blk"] = jnp.asarray(blk.weight[hot])
             self._tier_dev[use_rev] = d
@@ -1680,9 +1671,10 @@ class BSPEngine:
                         w[key] = a
                     nb = -(-cnt // sched.block_e)
                     b0 = st // sched.block_e
-                    base = np.full(sched.win_blocks, dims.seg, np.int32)
-                    base[:nb] = blk.base[p, b0:b0 + nb]
-                    w["blk_base"] = base
+                    ids = np.full((sched.win_blocks, blk.span), -1,
+                                  np.int32)
+                    ids[:nb] = blk.ids[p, b0:b0 + nb]
+                    w["blk_ids"] = ids
                     if blk.weight is not None:
                         a = np.zeros(win_e, np.float32)
                         a[:cnt] = blk.weight[p, st:st + cnt]
@@ -1945,8 +1937,19 @@ class BSPEngine:
         if tell.val is not None:
             self._fwd["t_val"] = jnp.asarray(tell.val)
 
+    def _note_path(self, program: VertexProgram) -> None:
+        """Count a fused or hybrid engine running ``program`` on the
+        reference path (no eligible EdgeMessage) as that backend's "xla"
+        path in ``kernels.ops.KERNEL_PATHS``."""
+        from repro.kernels.ops import KERNEL_PATHS
+
+        if self.backend != REFERENCE and not (
+                self._uses_hybrid(program) or self.fused_cfg_for(program)):
+            KERNEL_PATHS[(self.backend, "xla")] += 1
+
     def _step_fn(self, program: VertexProgram, edges: Optional[dict],
                  exchange: Callable, all_finished: Callable) -> Callable:
+        self._note_path(program)
         if self._uses_hybrid(program):
             cfg, arrs = self._hybrid_for(program)
             return functools.partial(_superstep_hybrid, program, cfg, arrs,
@@ -2072,9 +2075,9 @@ class BSPEngine:
                 _dopt_edges=jnp.zeros((q, parts), jnp.int32),
                 _dopt_switch=jnp.zeros((q, parts), jnp.int32))
         if modes["num_steps"]:
-            out = self._run_fixed_batched(program, num_steps, state)
+            out = self._fixed(program, num_steps, state)
             return self._dopt_finish(out) if use_dopt else out
-        out_state, steps_run = self._run_batched(program, state)
+        out_state, steps_run = self._converge(program, state)
         if use_dopt:
             out_state = self._dopt_finish(out_state)
         return out_state, steps_run
@@ -2091,8 +2094,24 @@ class BSPEngine:
             switches=s.sum(axis=1).astype(np.int64))
         return state
 
+    def _converge(self, program: VertexProgram,
+                  state: BatchedState) -> Tuple[BatchedState, Array]:
+        """Run-to-convergence dispatch (dynamic, tiered and distributed
+        engines shadow or override it).  The resident edge arrays enter the
+        jitted loop as operands: closed over, they would be embedded in the
+        program as constants, which at real graph sizes is gigabytes of
+        HLO and a second copy in device memory."""
+        return self._run_batched(program, self._edges_or_none(program),
+                                 state)
+
+    def _fixed(self, program: VertexProgram, num_steps: int,
+               state: BatchedState) -> BatchedState:
+        """Fixed-iteration dispatch; see :meth:`_converge`."""
+        return self._run_fixed_batched(program, num_steps,
+                                       self._edges_or_none(program), state)
+
     @functools.partial(jax.jit, static_argnums=(0, 1))
-    def _run_batched(self, program: VertexProgram,
+    def _run_batched(self, program: VertexProgram, edges: Optional[dict],
                      state: BatchedState) -> Tuple[BatchedState, Array]:
         """Advance a [Q, Pl, ...] batch of queries through **one** compiled
         ``lax.while_loop`` until every query votes finish; returns the final
@@ -2101,7 +2120,6 @@ class BSPEngine:
         Q never retrace, whatever their sources.  Private: dispatch through
         ``execute(program, state)`` — this stays a jitted class attribute
         because its compile cache is the serving contract's retrace gate."""
-        edges = self._edges_or_none(program)
         step_fn = self._step_fn(program, edges, self._exchange,
                                 self._all_finished)
         return _run_batched_loop(step_fn, program.max_steps, state,
@@ -2109,11 +2127,11 @@ class BSPEngine:
 
     @functools.partial(jax.jit, static_argnums=(0, 1, 2))
     def _run_fixed_batched(self, program: VertexProgram, num_steps: int,
+                           edges: Optional[dict],
                            state: BatchedState) -> BatchedState:
         """Fixed-iteration algorithms (PageRank), batched over queries.
         Private: dispatch through ``execute(program, state,
         num_steps=n)``."""
-        edges = self._edges_or_none(program)
         step_fn = self._step_fn(program, edges, self._exchange,
                                 self._all_finished)
 
@@ -2127,9 +2145,8 @@ class BSPEngine:
 
     @functools.partial(jax.jit, static_argnums=(0, 1, 2))
     def _run_chunk(self, program: VertexProgram, chunk: int,
-                   state: BatchedState, step: Array, fin: Array,
-                   steps_q: Array, poison: Array):
-        edges = self._edges_or_none(program)
+                   edges: Optional[dict], state: BatchedState, step: Array,
+                   fin: Array, steps_q: Array, poison: Array):
         self._guard.arm(poison)
         # The checked exchange tags every (partition, peer) slot block; the
         # hybrid step ignores the exchange callable (no outbox on a single
@@ -2180,8 +2197,8 @@ class BSPEngine:
                 program.max_steps, chunk, edges, dyn, state, step, fin,
                 steps_q)
             return out + (jnp.int32(0),)
-        return self._run_chunk(program, chunk, state, step, fin, steps_q,
-                               poison)
+        return self._run_chunk(program, chunk, self._edges_or_none(program),
+                               state, step, fin, steps_q, poison)
 
     def _run_batched_chunked(self, program: VertexProgram,
                              state: BatchedState, *, checkpoint_every: int,
@@ -2471,7 +2488,7 @@ class BSPEngine:
         cfg, arrs, _ = self._build_hybrid(program, self.pg.source,
                                           with_push=False)
         n = cfg.num_vertices
-        mul_ident = SEMIRINGS[cfg.semiring][3]
+        mul_ident = SEMIRINGS[cfg.semiring][2]
         ell_col = np.asarray(arrs["ell_col"])
         ell_val = np.asarray(arrs["ell_val"])
         kmax = ell_col.shape[1]
@@ -2632,7 +2649,7 @@ class BSPEngine:
         cfg, arrs, hg = self._build_hybrid(program, self.dg.mutated_csr(),
                                            with_push=True)
         n = cfg.num_vertices
-        mul_ident = SEMIRINGS[cfg.semiring][3]
+        mul_ident = SEMIRINGS[cfg.semiring][2]
         spare = self._dyn_ell_spare
         ell_col = np.pad(hg.ell_col, ((0, 0), (0, spare)),
                          constant_values=n)
@@ -2888,7 +2905,7 @@ class DistributedBSPEngine(BSPEngine):
         # The sharded path is already stale-constant-safe: edge arrays and
         # the mutation payload travel as shard_map operands rebuilt from the
         # engine's current binding on every call (see _dist_step_parts).
-        return DistributedBSPEngine._run_batched(self, program, state)
+        return DistributedBSPEngine._converge(self, program, state)
 
     def should_resplit_hybrid(self, threshold: float = 0.10) -> bool:
         # the distributed hybrid consumes mutations via forced compactions,
@@ -2964,7 +2981,7 @@ class DistributedBSPEngine(BSPEngine):
             layouts=self._shard_layouts)
         arrs = dict(n_vert=shd.n_vert, dense=shd.dense, ell_col=shd.ell_col,
                     ell_val=shd.ell_val, slot=shd.slot, hid=shd.hid,
-                    b_src=shd.b_src, b_local=shd.b_local, b_base=shd.b_base,
+                    b_src=shd.b_src, b_local=shd.b_local, b_ids=shd.b_ids,
                     b_mask=shd.b_mask, send_idx=shd.send_idx,
                     recv_ids=shd.recv_ids, loc_idx=shd.loc_idx,
                     loc_ids=shd.loc_ids)
@@ -3100,6 +3117,7 @@ class DistributedBSPEngine(BSPEngine):
         building the per-shard step function from them.  With ``guard``,
         every exchange runs checksummed (chunked windows pass the engine
         guard; the unguarded ``run``/``superstep`` paths pass None)."""
+        self._note_path(program)
         if self._uses_hybrid(program):
             shd, arrs = self._hybrid_dist_for(program)
             return arrs, (lambda extra:
@@ -3134,8 +3152,8 @@ class DistributedBSPEngine(BSPEngine):
 
         return edges, make, False
 
-    def _run_batched(self, program: VertexProgram,
-                     state: BatchedState) -> Tuple[BatchedState, Array]:
+    def _converge(self, program: VertexProgram,
+                  state: BatchedState) -> Tuple[BatchedState, Array]:
         """Advance a [Q, P, ...] batch of queries through one sharded
         ``lax.while_loop``; the termination vote is a per-query global AND
         (psum over the mesh axis).  Returns (batched state, steps [Q]).
@@ -3153,7 +3171,7 @@ class DistributedBSPEngine(BSPEngine):
             return _run_batched_loop(make_step(extra), program.max_steps,
                                      state, q)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(jax.tree.map(lambda _: spec, state),
                       jax.tree.map(lambda _: extra_spec, extra)),
@@ -3213,7 +3231,7 @@ class DistributedBSPEngine(BSPEngine):
                 # psum so the replicated out-spec holds the global count.
                 return st, stp, fn, sq, jax.lax.psum(bad, mesh_axis)
 
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 local_fn, mesh=self.mesh,
                 in_specs=(jax.tree.map(lambda _: spec, state),
                           jax.tree.map(lambda _: extra_spec, extra),
@@ -3256,7 +3274,7 @@ class DistributedBSPEngine(BSPEngine):
             self._validate_state(state)
             key = jax.tree_util.tree_structure(state)
             if key not in jitted:
-                sharded = shard_map(
+                sharded = jax.shard_map(
                     local_fn, mesh=self.mesh,
                     in_specs=(jax.tree.map(lambda _: spec, state),
                               jax.tree.map(lambda _: extra_spec, extra),

@@ -50,7 +50,7 @@ MIN_SR = "min"
 
 def add_identity(semiring: str) -> float:
     """⊕-identity of a semiring (0 for sum, +inf for min)."""
-    return SEMIRINGS[semiring][2]
+    return SEMIRINGS[semiring][1]
 
 
 @dataclasses.dataclass
@@ -299,8 +299,8 @@ class ShardHybridData:
     hid: np.ndarray           # [S, pl, v_max] slot -> hybrid id (pad = n_max)
     # --- boundary edges, sorted by flat outbox slot id ---
     b_src: np.ndarray         # [S, be_pad] hybrid source id (pad -> n_max)
-    b_local: np.ndarray       # [S, be_pad] slot id − block base
-    b_base: np.ndarray        # [S, nb] per-block base slot ids
+    b_local: np.ndarray       # [S, be_pad] rank of the edge's slot in block
+    b_ids: np.ndarray         # [S, nb, span] per-block distinct slot ids
     b_mask: np.ndarray        # [S, be_pad] 1 for real edges
     b_weight: Optional[np.ndarray]   # [S, be_pad] f32 or None
     b_span: int               # static span bound for the outbox kernel
@@ -441,7 +441,7 @@ def shard_degree_split(pg: PartitionedGraph, num_shards: int, semiring: str,
             "use_reverse programs; partition with include_reverse=True")
     o_max = ea.o_max
     ident = add_identity(semiring)
-    mul_ident = SEMIRINGS[semiring][3]
+    mul_ident = SEMIRINGS[semiring][2]
     if layouts is None or use_reverse:
         layouts = _shard_intra(pg, S, g)
 
@@ -523,7 +523,7 @@ def shard_degree_split(pg: PartitionedGraph, num_shards: int, semiring: str,
             b_w_rows[s, :k] = bw
         counts[s] = k
     # Reuse the fused-path block preprocessing: rows sorted by "dst_ext"
-    # (here: flat slot id) → per-block base/local/span for the outbox kernel.
+    # (here: flat slot id) → per-block ids/local/span for the outbox kernel.
     blk = build_block_metadata(
         EdgeArrays(src=b_src_rows, dst_ext=b_flat, weight=b_w_rows,
                    edge_mask=b_mask_rows,
@@ -531,7 +531,7 @@ def shard_degree_split(pg: PartitionedGraph, num_shards: int, semiring: str,
                    outbox_mask=np.zeros((S, S, 1), bool),
                    inbox_dst=np.zeros((S, S, 1), np.int32),
                    num_edges=counts),
-        block_e=block_e, lane=align)
+        block_e=block_e)
 
     # ---- compact exchange maps --------------------------------------------
     pair_counts = np.zeros((S, S), dtype=np.int64)
@@ -598,7 +598,7 @@ def shard_degree_split(pg: PartitionedGraph, num_shards: int, semiring: str,
         num_parts=P, o_max=o_max, k_dense=K, n_max=n_max,
         num_slots=num_slots, n_vert=n_vert, dense=dense,
         ell_col=ell_col, ell_val=ell_val, slot=slot, hid=hid,
-        b_src=blk.src, b_local=blk.local, b_base=blk.base,
+        b_src=blk.src, b_local=blk.local, b_ids=blk.ids,
         b_mask=blk.mask, b_weight=blk.weight, b_span=blk.span,
         b_block=block_e, send_idx=send_idx, recv_ids=recv_ids,
         loc_idx=loc_idx, loc_ids=loc_ids, wire_width=w_pad,

@@ -338,28 +338,30 @@ class BlockMetadata:
     """Static per-edge-block metadata for the fused superstep kernel.
 
     ``partition`` sorts each partition's edges by extended destination, so a
-    block of ``block_e`` consecutive edges touches a contiguous span of
-    segment ids.  This precomputes, per 128-aligned block: the base (minimum)
-    segment id, each edge's local offset within the block's span, and the
-    measured span itself — everything the one-hot MXU reduction needs to be
-    gather/scatter-free.  Padding edges (``mask`` False) are assigned the
-    preceding real edge's segment id so they never widen a block's span; the
-    kernel masks their messages to the combine identity.
+    block of ``block_e`` consecutive edges touches a sorted run of segment
+    ids.  This precomputes, per block: its distinct segment ids in
+    ascending order (``ids``), each edge's rank among them (``local``), and
+    the lane-aligned bound on distinct ids per block (``span`` ≤
+    ``block_e``) — everything the one-hot reduction needs to be
+    gather/scatter-free, whatever gaps the segment space has between
+    blocks' ids.  Padding edges (``mask`` False) take the preceding real
+    edge's segment so they never add a distinct id; the kernel masks their
+    messages to the combine identity.
     """
 
     block_e: int
-    span: int               # lane-aligned span bound the kernel compiles for
-    span_req: int           # measured max over blocks (pre-alignment)
-    base: np.ndarray        # [P, nb] int32: first segment id of each block
-    local: np.ndarray       # [P, e_pad] int32: segment id − block base
+    span: int               # lane-aligned distinct-id bound the kernel uses
+    span_req: int           # measured max distinct ids over blocks
+    ids: np.ndarray         # [P, nb, span] int32: distinct ids (pad = -1)
+    local: np.ndarray       # [P, e_pad] int32: rank of the edge's segment
     src: np.ndarray         # [P, e_pad] int32: src, zero-padded
     mask: np.ndarray        # [P, e_pad] int32: 1 for real edges
     weight: Optional[np.ndarray]  # [P, e_pad] f32 or None
-    block_spans: np.ndarray  # [P, nb] int32: measured span of each block
+    block_spans: np.ndarray  # [P, nb] int32: segment-id range of each block
 
     @property
     def num_blocks(self) -> int:
-        return self.base.shape[1]
+        return self.ids.shape[1]
 
     @property
     def e_pad(self) -> int:
@@ -419,11 +421,17 @@ def build_block_metadata(ea: EdgeArrays, *, block_e: int = 1024,
 
     nb = e_pad // block_e
     blocks = filled.reshape(P, nb, block_e)
-    base = blocks[:, :, 0].astype(np.int32)
-    block_spans = (blocks.max(axis=2) - base + 1).astype(np.int32)
-    span_req = int(block_spans.max()) if block_spans.size else 1
+    block_spans = (blocks[:, :, -1] - blocks[:, :, 0] + 1).astype(np.int32)
+    # Rows are sorted, so an id's rank in its block counts the id changes
+    # before it.
+    new_id = np.ones(blocks.shape, dtype=bool)
+    new_id[:, :, 1:] = blocks[:, :, 1:] != blocks[:, :, :-1]
+    rank = np.cumsum(new_id, axis=2, dtype=np.int32) - 1
+    span_req = int(rank[:, :, -1].max()) + 1 if rank.size else 1
     span = max(_round_up(span_req, lane), lane)
-    local = (blocks - base[:, :, None]).reshape(P, e_pad).astype(np.int32)
+    ids = np.full((P, nb, span), -1, dtype=np.int32)
+    np.put_along_axis(ids, rank, blocks.astype(np.int32), axis=2)
+    local = rank.reshape(P, e_pad)
 
     src = np.pad(ea.src, ((0, 0), (0, e_pad - e_max))).astype(np.int32)
     mask = np.pad(ea.edge_mask, ((0, 0), (0, e_pad - e_max))
@@ -431,7 +439,7 @@ def build_block_metadata(ea: EdgeArrays, *, block_e: int = 1024,
     weight = (np.pad(ea.weight, ((0, 0), (0, e_pad - e_max))
                      ).astype(np.float32) if ea.weight is not None else None)
     return BlockMetadata(block_e=block_e, span=span, span_req=span_req,
-                         base=base, local=local, src=src, mask=mask,
+                         ids=ids, local=local, src=src, mask=mask,
                          weight=weight, block_spans=block_spans)
 
 

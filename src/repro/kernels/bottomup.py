@@ -22,8 +22,8 @@ would examine:
   - ``early_exit=False`` (CC labels, SSSP distances — messages differ per
     parent): the full ``kreal[v]`` real slots.
 
-The reduction itself always covers every slot (the VPU form is a vectorized
-gather + row-min, bitwise identical to ``ell_spmv``'s — that's the parity
+The reduction itself always covers every slot (the same XLA gather and
+slot-axis min as ``ell_spmv``, bitwise identical — that's the parity
 guarantee); ``scanned`` is the deterministic *work model* of the sequential
 scan a scalar core (or a chunked-K TPU kernel that breaks once a whole row
 block has hit) would perform.  Under the same uniformity licence a row's
@@ -35,7 +35,8 @@ on.
 
 ``kreal[v]`` is the row's real (non-sentinel) slot count; sentinel slots
 gather the +inf sink and can never register a hit, so rows report at most
-their real work.  x carries the query-batch axis exactly as in ell_spmv.
+their real work.  The gathered values carry the query-batch axis and the
+slot-major ``[Q, K, V]`` layout of ell_spmv, and the grid is the same.
 """
 from __future__ import annotations
 
@@ -45,82 +46,80 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ell_spmv import accumulate, row_reduce
 
-def _scan_counts(gathered, kreal, early_exit: bool):
-    """Slots a sequential early-exit scan would touch, per row."""
+
+def _bu_kernel(g_ref, *rest, semiring: str, early_exit: bool,
+               block_k: int, k_total: int):
+    if semiring == "min_plus":
+        v_ref, kreal_ref, o_ref, s_ref = rest
+        vals = v_ref[...]
+    else:
+        kreal_ref, o_ref, s_ref = rest
+        vals = None
+    g = g_ref[...]                                   # [Q, bk, bv]
+    accumulate(o_ref, row_reduce(g, vals, semiring), "min")
+    k = pl.program_id(1)
+    kreal = jnp.broadcast_to(kreal_ref[...], s_ref.shape)
     if not early_exit:
-        return kreal
-    k = gathered.shape[1]
-    hit = gathered < jnp.inf
-    idx = jax.lax.broadcasted_iota(jnp.int32, gathered.shape, 1)
-    first = jnp.min(jnp.where(hit, idx, k), axis=1)
-    return jnp.minimum(first + 1, kreal)
+        s_ref[...] = kreal
+        return
+    # A "hit" is a live *parent* (x finite), judged before the ⊗ add — the
+    # scan stops on reaching any frontier in-neighbour.  Rows with no hit
+    # keep k_total, which min(· + 1, kreal) turns into their kreal.
+    idx = k * block_k + jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    first = jnp.min(jnp.where(g < jnp.inf, idx, k_total), axis=1)
 
+    @pl.when(k == 0)
+    def _init():
+        s_ref[...] = first
 
-def _bu_kernel_min(col_ref, kreal_ref, x_ref, o_ref, s_ref, *,
-                   early_exit: bool):
-    cols = col_ref[...]                      # [bv, K] int32
-    x = x_ref[0]                             # [x_len]: this query's row
-    gathered = jnp.take(x, cols, axis=0)     # [bv, K]
-    o_ref[...] = jnp.min(gathered, axis=1)[None]
-    s_ref[...] = _scan_counts(gathered, kreal_ref[..., 0], early_exit)[None]
+    @pl.when(k > 0)
+    def _fold():
+        s_ref[...] = jnp.minimum(s_ref[...], first)
 
-
-def _bu_kernel_min_plus(col_ref, val_ref, kreal_ref, x_ref, o_ref, s_ref, *,
-                        early_exit: bool):
-    cols = col_ref[...]
-    vals = val_ref[...]
-    x = x_ref[0]
-    gathered = jnp.take(x, cols, axis=0)
-    o_ref[...] = jnp.min(gathered + vals, axis=1)[None]
-    # A "hit" is a live *parent* (x finite), judged before the ⊗ add —
-    # the scan stops on reaching any frontier in-neighbour.
-    s_ref[...] = _scan_counts(gathered, kreal_ref[..., 0], early_exit)[None]
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _finish():
+        s_ref[...] = jnp.minimum(s_ref[...] + 1, kreal)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("semiring", "early_exit", "block_v",
-                                    "interpret"))
-def bottomup_scan(col: jax.Array, val: jax.Array | None, x: jax.Array,
-                  kreal: jax.Array, *, semiring: str,
-                  early_exit: bool = False, block_v: int = 512,
-                  interpret: bool = False):
-    """Bottom-up scan over a (query, row-block) grid.
+                                    "block_k", "interpret"))
+def bottomup_scan(g: jax.Array, val_t: jax.Array | None, kreal: jax.Array,
+                  *, semiring: str, early_exit: bool = False,
+                  block_v: int, block_k: int, interpret: bool = False):
+    """Bottom-up scan over a (row-block, slot-block) grid.
 
-    col: [V, K] int32 in-neighbour ids into ``x`` (sentinel = x_len-1);
-    val: [V, K] f32 (``min_plus``) or None (``min``); x: [Q, x_len] with the
-    ⊕-identity sink appended per row; kreal: [V, 1] int32 real slot counts.
-    Returns ``(y [Q, V] f32, scanned [Q, V] int32)``.  V must be a multiple
-    of block_v (ops.py pads).
+    g: [Q, K, V] f32 gathered in-neighbour values (``x[col.T]``, the
+    ⊕-identity sink at sentinel slots); val_t: [K, V] f32 (``min_plus``)
+    or None (``min``); kreal: [1, V] int32 real slot counts.  Returns
+    ``(y [Q, V] f32, scanned [Q, V] int32)``.  V must be a multiple of
+    block_v and K of block_k (ops.py pads).
     """
     if semiring not in ("min", "min_plus"):
         raise ValueError(f"bottom-up scan needs a min combine, "
                          f"got {semiring!r}")
-    v, k = col.shape
-    q = x.shape[0]
-    assert x.ndim == 2, "ops.bottomup_scan_op adds the query-batch axis"
-    assert v % block_v == 0, "ops.bottomup_scan_op pads to block multiples"
-    assert kreal.shape == (v, 1)
-    row_specs = [pl.BlockSpec((block_v, k), lambda b, i: (i, 0))]
-    args = [col]
+    q, k, v = g.shape
+    assert v % block_v == 0 and k % block_k == 0, "ops.bottomup_scan_op pads"
+    assert kreal.shape == (1, v)
+    in_specs = [pl.BlockSpec((q, block_k, block_v), lambda i, j: (0, j, i))]
+    args = [g]
     if semiring == "min_plus":
-        assert val is not None and val.shape == (v, k)
-        kernel = functools.partial(_bu_kernel_min_plus, early_exit=early_exit)
-        row_specs.append(pl.BlockSpec((block_v, k), lambda b, i: (i, 0)))
-        args.append(val)
-    else:
-        kernel = functools.partial(_bu_kernel_min, early_exit=early_exit)
+        in_specs.append(pl.BlockSpec((block_k, block_v),
+                                     lambda i, j: (j, i)))
+        args.append(val_t)
+    in_specs.append(pl.BlockSpec((1, block_v), lambda i, j: (0, i)))
+    out_spec = pl.BlockSpec((q, block_v), lambda i, j: (0, i))
+    kernel = functools.partial(_bu_kernel, semiring=semiring,
+                               early_exit=early_exit, block_k=block_k,
+                               k_total=k)
     return pl.pallas_call(
         kernel,
-        grid=(q, v // block_v),
-        in_specs=row_specs + [
-            pl.BlockSpec((block_v, 1), lambda b, i: (i, 0)),
-            # one query's x row, VMEM resident across its row blocks
-            pl.BlockSpec((1, x.shape[1]), lambda b, i: (b, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, block_v), lambda b, i: (b, i)),
-                   pl.BlockSpec((1, block_v), lambda b, i: (b, i))],
+        grid=(v // block_v, k // block_k),
+        in_specs=in_specs,
+        out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((q, v), jnp.float32),
                    jax.ShapeDtypeStruct((q, v), jnp.int32)],
         interpret=interpret,
-    )(*args, kreal, x)
+    )(*args, kreal)

@@ -7,7 +7,7 @@ and the CSR→ELL / CSR→dense-block packing used by the hybrid engine.
 """
 from __future__ import annotations
 
-import functools
+import collections
 from typing import Tuple
 
 import jax
@@ -20,30 +20,29 @@ from repro.kernels import ell_spmv as _ell
 from repro.kernels import flash_attention as _flash
 
 
-# jax 0.4.x ships lax.optimization_barrier without a vmap rule; the barrier
-# is dim-wise transparent, so batching is operand pass-through.  Newer jax
-# registers its own rule — the guard keeps this a no-op there.  The barrier
-# is how kernel callers pin FMA-contraction seams (see hybrid_spmv and the
-# out-of-core tiered path, which must round bitwise-identically).
-from jax.interpreters import batching as _batching  # noqa: E402
-
-if jax.lax.optimization_barrier_p not in _batching.primitive_batchers:
-    def _barrier_batcher(args, dims):
-        return jax.lax.optimization_barrier_p.bind(*args), dims
-    _batching.primitive_batchers[jax.lax.optimization_barrier_p] = \
-        _barrier_batcher
-
-
 def pin(x: jax.Array) -> jax.Array:
-    """``lax.optimization_barrier`` with the vmap shim above guaranteed
-    registered — importing this function is what loads the rule, so
-    callers outside the kernel layer (e.g. an ``apply_fn`` that must not
-    be FMA-contracted) use this spelling."""
+    """``lax.optimization_barrier``: pins an FMA-contraction seam.
+
+    Kernel callers (``hybrid_spmv``, the tiered path) and ``apply_fn``s
+    that must round bitwise-identically across backends use it so XLA
+    cannot fuse a multiply-add across the seam."""
     return jax.lax.optimization_barrier(x)
 
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
+
+
+# The compute path each kernel call site took, counted as calls are traced:
+# ``(site, path)`` with path "mosaic" (the compiled Pallas kernel),
+# "interpret" (the Pallas interpreter, off-TPU) or "xla" (the plain XLA
+# chain a fused site takes when its blocks do not fit the kernel).  Paths
+# are static per trace, so this is what every run of that trace executes.
+KERNEL_PATHS: collections.Counter = collections.Counter()
+
+
+def _record(site: str, interpret: bool) -> None:
+    KERNEL_PATHS[(site, "interpret" if interpret else "mosaic")] += 1
 
 
 def _pad_to(x, mult: int, axis: int, value=0):
@@ -71,6 +70,7 @@ def dense_spmv_op(x: jax.Array, a: jax.Array, *, block: int = 256,
     bn = min(block, max(128, 1 << (n - 1).bit_length()))
     xp = _pad_to(x, bk, 1)
     ap = _pad_to(_pad_to(a, bk, 0), bn, 1)
+    _record("dense_spmv", interpret)
     y = _dense.dense_spmv(xp, ap, block_n=bn, block_k=bk,
                           interpret=interpret)
     return y[:, :n]
@@ -87,6 +87,7 @@ def dense_spmv_minplus_op(x: jax.Array, a: jax.Array, *, block: int = 256,
     bn = min(block, max(128, 1 << (n - 1).bit_length()))
     xp = _pad_to(x, bk, 1, value=jnp.inf)
     ap = _pad_to(_pad_to(a, bk, 0, value=jnp.inf), bn, 1, value=jnp.inf)
+    _record("dense_spmv_minplus", interpret)
     y = _dense.dense_spmv_minplus(xp, ap, block_n=bn, block_k=bk,
                                   interpret=interpret)
     return y[:, :n]
@@ -123,7 +124,7 @@ def csr_to_ell(g: CSRGraph, combine: str | None = None,
     deg = gg.out_degrees()
     kmax = max(int(deg.max()) if len(deg) else 1, 1)
     n = gg.num_vertices
-    mul_ident = _ell.SEMIRINGS[sr][3]
+    mul_ident = _ell.SEMIRINGS[sr][2]
     col = np.full((n, kmax), n, dtype=np.int32)
     val = np.full((n, kmax), mul_ident, dtype=np.float32)
     # Vectorized ELL pack: each edge's (row, slot) from its rank within the
@@ -144,11 +145,33 @@ def csr_to_ell(g: CSRGraph, combine: str | None = None,
     return col, val, kmax
 
 
+def _ell_gather(col: jax.Array, val: jax.Array | None, x: jax.Array,
+                block_v: int, mul_ident: float):
+    """Pad the ELL rows and slots to the kernel's blocks and gather the
+    slot-major ``[Q, K, V]`` source values ``x[col.T]`` in XLA.
+
+    Padding rows and slots point at the sentinel (x's last column, the
+    ⊕-identity sink) with ⊗-identity values.  Returns
+    ``(g, val_t, bv, bk)``."""
+    v, k = col.shape
+    sentinel = x.shape[1] - 1           # callers append the ⊕-identity slot
+    bv = min(block_v, max(128, -(-v // 128) * 128))
+    bk = _ell.slot_block(x.shape[0], k, block_v)
+    col_t = _pad_to(_pad_to(col.T, bk, 0, value=sentinel), bv, 1,
+                    value=sentinel)
+    g = jnp.take(x, col_t, axis=1, mode="clip")          # [Q, Kp, Vp]
+    val_t = None
+    if val is not None:
+        val_t = _pad_to(_pad_to(val.T, bk, 0, value=mul_ident), bv, 1,
+                        value=mul_ident)
+    return g, val_t, bv, bk
+
+
 def ell_spmv_op(col: jax.Array, val: jax.Array, x: jax.Array, *,
                 combine: str | None = None, semiring: str | None = None,
                 block_v: int = 512,
                 interpret: bool | None = None) -> jax.Array:
-    """ELL SpMV for arbitrary V; pads rows to the block size.
+    """ELL SpMV for arbitrary V and K; pads rows and slots to the blocks.
 
     ``x`` may be ``[x_len]`` (one query, returns ``[V]``) or ``[Q, x_len]``
     (query batch, returns ``[Q, V]``); the topology is shared across Q.
@@ -160,12 +183,10 @@ def ell_spmv_op(col: jax.Array, val: jax.Array, x: jax.Array, *,
     squeeze = x.ndim == 1
     if squeeze:
         x = x[None]
-    bv = min(block_v, max(8, 1 << (v - 1).bit_length()))
-    mul_ident = _ell.SEMIRINGS[sr][3]
-    sentinel = x.shape[1] - 1  # callers append the ⊕-identity slot
-    colp = _pad_to(col, bv, 0, value=sentinel)
-    valp = _pad_to(val, bv, 0, value=mul_ident)
-    y = _ell.ell_spmv(colp, valp, x, semiring=sr, block_v=bv,
+    g, val_t, bv, bk = _ell_gather(col, None if sr == "min" else val, x,
+                                   block_v, _ell.SEMIRINGS[sr][2])
+    _record("ell_spmv", interpret)
+    y = _ell.ell_spmv(g, val_t, semiring=sr, block_v=bv, block_k=bk,
                       interpret=interpret)[:, :v]
     return y[0] if squeeze else y
 
@@ -202,15 +223,13 @@ def bottomup_scan_op(col: jax.Array, val: jax.Array | None, x: jax.Array,
         x = x[None]
         if skip is not None and skip.ndim == 1:
             skip = skip[None]
-    bv = min(block_v, max(8, 1 << (v - 1).bit_length()))
-    sentinel = x.shape[1] - 1  # callers append the ⊕-identity slot
-    colp = _pad_to(col, bv, 0, value=sentinel)
-    valp = (_pad_to(val, bv, 0, value=_ell.SEMIRINGS[semiring][3])
-            if val is not None else None)
-    krealp = _pad_to(kreal.astype(jnp.int32), bv, 0)[:, None]
-    y, scanned = _bu.bottomup_scan(colp, valp, x, krealp, semiring=semiring,
+    g, val_t, bv, bk = _ell_gather(col, val, x, block_v,
+                                   _ell.SEMIRINGS[semiring][2])
+    krealp = _pad_to(kreal.astype(jnp.int32), bv, 0)[None]
+    _record("bottomup_scan", interpret)
+    y, scanned = _bu.bottomup_scan(g, val_t, krealp, semiring=semiring,
                                    early_exit=early_exit, block_v=bv,
-                                   interpret=interpret)
+                                   block_k=bk, interpret=interpret)
     y, scanned = y[:, :v], scanned[:, :v]
     if skip is not None and early_exit:
         scanned = jnp.where(skip, 0, scanned)
@@ -301,45 +320,74 @@ def segment_reduce_op(msgs: jax.Array, seg_ids: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# shared two-phase plumbing of the fused and outbox kernels
+# ---------------------------------------------------------------------------
+
+def _pad_blocks(arrays, block_e: int, ids: jax.Array):
+    """Pad edge arrays ``[..., e_pad]`` (and the ``[..., nb, span]`` id
+    table) to whole kernel steps of ``BLOCKS_PER_STEP`` blocks when there
+    are more blocks than one step takes; padding edges are masked out
+    (zeros) and their blocks' ids are sinks (-1)."""
+    from repro.kernels.fused_superstep import BLOCKS_PER_STEP
+
+    if ids.shape[-2] <= BLOCKS_PER_STEP:
+        return arrays, ids
+    step_e = BLOCKS_PER_STEP * block_e
+    arrays = [None if a is None else _pad_to(a, step_e, a.ndim - 1)
+              for a in arrays]
+    return arrays, _pad_to(ids, BLOCKS_PER_STEP, ids.ndim - 2, value=-1)
+
+
+def _merge_partials(partials: jax.Array, ids: jax.Array, num_segments: int,
+                    combine: str) -> jax.Array:
+    """Phase 2: ⊕-merge block partials ``[G, nb, span]`` into
+    ``[G, num_segments]`` (blocks may share a boundary segment); pad
+    columns (id -1) drop into a sink."""
+    seg_op = jax.ops.segment_sum if combine == "sum" else jax.ops.segment_min
+    g = partials.shape[0]
+    ids = jnp.where(ids >= 0, ids, num_segments)
+    offs = (jnp.arange(g, dtype=jnp.int32) * (num_segments + 1)).reshape(
+        (g,) + (1,) * (ids.ndim - 1))
+    acc = seg_op(partials.ravel(), (ids + offs).ravel(),
+                 num_segments=g * (num_segments + 1))
+    return acc.reshape(g, num_segments + 1)[:, :num_segments]
+
+
+# ---------------------------------------------------------------------------
 # source-side outbox aggregation (distributed hybrid boundary leg, §3.4)
 # ---------------------------------------------------------------------------
 
 def outbox_reduce_op(x: jax.Array, src: jax.Array, local: jax.Array,
-                     mask: jax.Array, base: jax.Array, weight, *,
+                     mask: jax.Array, ids: jax.Array, weight, *,
                      num_slots: int, combine: str = "sum", weight_op=None,
                      span: int, block_e: int = 256, max_span: int = 4096,
-                     gather_chunk: int = 256,
                      interpret: bool | None = None) -> jax.Array:
     """Reduce boundary messages into the flat outbox-slot space.
 
     ``x`` is one shard's per-query per-vertex message matrix ``[Q, x_len]``
     (+ identity sink at the end of each row; a 1-D ``x`` is treated as
-    ``Q=1``); ``src``/``local``/``mask``/``base``/``weight`` follow
+    ``Q=1``); ``src``/``local``/``mask``/``ids``/``weight`` follow
     ``hybrid.shard_degree_split`` — boundary edges sorted by flat slot id
-    with per-block base/local offsets, arriving as *operands* so each shard
-    carries its own maps under ``shard_map`` (and shared across the query
-    batch).  ``weight_op`` is the EdgeMessage's ⊗ ("add"/"mul"/None).
-    Returns the [Q, num_slots] aggregated outboxes (⊕-identity for unused
-    slots), or [num_slots] for 1-D input.
+    with each block's distinct slot ids and per-edge ranks, arriving as
+    *operands* so each shard carries its own maps under ``shard_map`` (and
+    shared across the query batch).  ``weight_op`` is the EdgeMessage's ⊗
+    ("add"/"mul"/None).  Returns the [Q, num_slots] aggregated outboxes
+    (⊕-identity for unused slots), or [num_slots] for 1-D input.
 
-    Falls back to the plain gather → ``jax.ops.segment_*`` chain when the
-    static ``span`` bound exceeds ``max_span`` or the VMEM budget for the
-    kernel's [block_e, span] intermediates — correctness never depends on
-    the kernel (same contract as ``fused_superstep_op``).
+    Takes the plain gather → ``jax.ops.segment_*`` chain (recorded as the
+    "xla" path in ``KERNEL_PATHS``) when ``span`` exceeds ``max_span`` or
+    the VMEM budget for the kernel's [block_e, span] intermediates.
     """
+    from repro.kernels import fused_superstep as _fused
     from repro.kernels import outbox_reduce as _obox
 
     if interpret is None:
         interpret = _interpret_default()
     ident = 0.0 if combine == "sum" else jnp.inf
-    seg_op = jax.ops.segment_sum if combine == "sum" else jax.ops.segment_min
     squeeze = x.ndim == 1
     if squeeze:
         x = x[None]
-    q = x.shape[0]
-    e_pad = src.shape[0]
-    nb = e_pad // block_e
-    q_offs = (jnp.arange(q, dtype=jnp.int32) * (num_slots + 1))
+    nb = src.shape[0] // block_e
 
     def apply_weight(msgs):
         if weight_op == "add":
@@ -349,31 +397,26 @@ def outbox_reduce_op(x: jax.Array, src: jax.Array, local: jax.Array,
         return msgs
 
     if span > fused_span_limit(block_e, combine, max_span):
-        # Reference chain: reconstruct flat slot ids from base + local.
-        ids = (jnp.repeat(base, block_e) + local).astype(jnp.int32)
+        KERNEL_PATHS[("outbox_reduce", "xla")] += 1
+        # Reference chain: each edge's flat slot id from its block's table.
+        slot = jnp.take_along_axis(ids, local.reshape(nb, block_e), axis=1)
         msgs = apply_weight(jnp.take(x, src, axis=1))       # [Q, e_pad]
         msgs = jnp.where(mask > 0, msgs, ident)
-        ids = jnp.minimum(ids, num_slots)[None] + q_offs[:, None]
-        acc = seg_op(msgs.ravel(), ids.ravel(),
-                     num_segments=q * (num_slots + 1))
-        acc = acc.reshape(q, num_slots + 1)[:, :num_slots]
+        acc = _merge_partials(msgs[:, None, :], slot.reshape(1, -1),
+                              num_slots, combine)
         return acc[0] if squeeze else acc
 
-    x_pad = _pad_to(x, gather_chunk, 1, value=ident)
+    _record("outbox_reduce", interpret)
+    x_pad = _pad_to(x, _fused.TILE, 1, value=ident)
+    (src, local, mask, weight), ids = _pad_blocks(
+        [src, local, mask, weight if weight_op is not None else None],
+        block_e, ids)
     partials = _obox.outbox_reduce_blocks(
-        x_pad, src, local, mask,
-        weight if weight_op is not None else None, combine=combine,
+        x_pad, src, local, mask, weight, combine=combine,
         weight_op=weight_op, span=span, block_e=block_e,
-        gather_chunk=gather_chunk, interpret=interpret)     # [Q, nb, span]
-
-    # phase 2: merge block partials (blocks may share a boundary slot);
-    # span overhang past the slot space drops into a sink.
-    ids = jnp.minimum(base[:, None] + jnp.arange(span, dtype=jnp.int32),
-                      num_slots)                            # [nb, span]
-    ids = ids[None] + q_offs[:, None, None]
-    acc = seg_op(partials.ravel(), ids.ravel(),
-                 num_segments=q * (num_slots + 1))
-    acc = acc.reshape(q, num_slots + 1)[:, :num_slots]
+        interpret=interpret)                                # [Q, nb, span]
+    acc = _merge_partials(partials, jnp.broadcast_to(
+        ids[None], partials.shape), num_slots, combine)
     return acc[0] if squeeze else acc
 
 
@@ -382,10 +425,11 @@ def outbox_reduce_op(x: jax.Array, src: jax.Array, local: jax.Array,
 # ---------------------------------------------------------------------------
 
 # VMEM byte budget for the kernel's dominant [block_e, span] intermediates
-# (one f32 one-hot for sum; a bool hit + f32 select pair for min).  A TPU
-# core has ~16 MiB of VMEM; half is left for the state block, edge blocks,
-# gather scratch, and output partials.
+# (the one-hot select, its hit mask, and for min a second select).
 _VMEM_BLOCK_BUDGET = 8 << 20
+# The whole kernel — the VMEM-resident partition state included — must fit
+# this share of a v5e core's 128 MiB of VMEM (fused_superstep.vmem_bytes).
+_VMEM_KERNEL_BUDGET = 96 << 20
 
 
 def fused_span_limit(block_e: int, combine: str = "sum",
@@ -394,8 +438,8 @@ def fused_span_limit(block_e: int, combine: str = "sum",
 
     The caller's ``max_span`` bounds reassociation span; on top of that the
     [block_e, span] intermediates must fit the VMEM budget — ``min`` combines
-    materialize two such arrays, halving the limit.  Spans above this fall
-    back to the reference path (see ``fused_superstep_op``).
+    materialize two such arrays, halving the limit.  Spans above this take
+    the XLA chain (see ``fused_superstep_op``).
     """
     copies = 2 if combine == "min" else 1
     return min(max_span, _VMEM_BLOCK_BUDGET // (4 * block_e * copies))
@@ -403,44 +447,47 @@ def fused_span_limit(block_e: int, combine: str = "sum",
 
 def fused_superstep_op(msg_fn, vstate: jax.Array, weight, scal: jax.Array,
                        src: jax.Array, local: jax.Array, mask: jax.Array,
-                       base: jax.Array, dst_ext: jax.Array, *,
+                       ids: jax.Array, dst_ext: jax.Array, *,
                        num_segments: int, combine: str = "sum", span: int,
                        block_e: int = 1024, max_span: int = 4096,
-                       gather_chunk: int = 256,
                        interpret: bool | None = None) -> jax.Array:
     """Fused compute phase: per-query accumulator [Q, Pl, num_segments].
 
     Inputs follow ``partition.build_block_metadata``: ``vstate`` is the
-    stacked [Q, Pl, K, v_pad] gathered-state matrix, ``scal`` [Q, Pl, S]
+    stacked [Q, Pl, K, v] gathered-state matrix, ``scal`` [Q, Pl, S]
     carries (step, *per-query per-partition consts), ``src``/``local``/
     ``mask`` are the [Pl, e_pad] block arrays (shared across the query
-    batch), ``base`` [Pl, nb] the per-block segment bases, and
+    batch), ``ids`` [Pl, nb, span] each block's distinct segment ids, and
     ``span``/``block_e`` their static geometry.  ``msg_fn(vals, weight,
     scals) -> msgs`` is elementwise/broadcast-safe, so the same callable
-    runs on [be]-shaped values inside the kernel and on
-    [Q, Pl, e_max]-shaped values in the fallback.
+    runs on [be, 1]-shaped values inside the kernel and on
+    [Q, Pl, e_max]-shaped values in the XLA chain.
 
-    Falls back to the reference gather → message → ``jax.ops.segment_*``
-    chain when the measured block span exceeds ``fused_span_limit`` — either
-    ``max_span`` (adversarially gappy destinations) or the VMEM budget for
-    the kernel's [block_e, span] intermediates.  Correctness never depends
-    on the kernel, the same contract as ``segment_reduce_op``.
+    Takes the reference gather → message → ``jax.ops.segment_*`` chain
+    (recorded as the "xla" path in ``KERNEL_PATHS``) when the block span
+    exceeds ``fused_span_limit`` or the kernel's whole VMEM footprint,
+    resident state included, exceeds the VMEM budget.
     """
     from repro.kernels import fused_superstep as _fused
 
     if interpret is None:
         interpret = _interpret_default()
-    q, pl_count = vstate.shape[0], vstate.shape[1]
+    q, pl_count, n_keys = vstate.shape[:3]
     ident = 0.0 if combine == "sum" else jnp.inf
     seg_op = jax.ops.segment_sum if combine == "sum" else jax.ops.segment_min
+    v_pad = -(-vstate.shape[3] // _fused.TILE) * _fused.TILE
+    n_edge = 4 if weight is not None else 3
+    vmem = _fused.vmem_bytes(n_keys, v_pad, block_e, span, n_edge)
 
-    if span > fused_span_limit(block_e, combine, max_span):
+    if (span > fused_span_limit(block_e, combine, max_span)
+            or vmem > _VMEM_KERNEL_BUDGET):
+        KERNEL_PATHS[("fused_superstep", "xla")] += 1
         # Reference path expressed through the elementwise form.
         e_max = dst_ext.shape[1]
         src_b = jnp.broadcast_to(src[None, :, :e_max], (q, pl_count, e_max))
         vals = tuple(
             jnp.take_along_axis(vstate[:, :, k_, :], src_b, axis=2)
-            for k_ in range(vstate.shape[2]))
+            for k_ in range(n_keys))
         scals = tuple(scal[:, :, j:j + 1] for j in range(scal.shape[2]))
         w = weight[:, :e_max] if weight is not None else None
         msgs = msg_fn(vals, w, scals).astype(jnp.float32)
@@ -451,17 +498,17 @@ def fused_superstep_op(msg_fn, vstate: jax.Array, weight, scal: jax.Array,
                      num_segments=q * pl_count * num_segments)
         return acc.reshape(q, pl_count, num_segments)
 
+    _record("fused_superstep", interpret)
+    vstate = _pad_to(vstate, _fused.TILE, 3)
+    (src, local, mask, weight), ids = _pad_blocks(
+        [src, local, mask, weight], block_e, ids)
     partials = _fused.fused_superstep_blocks(
         vstate, scal, src, local, mask, weight, msg_fn=msg_fn,
         combine=combine, span=span, block_e=block_e,
-        gather_chunk=gather_chunk, interpret=interpret)  # [Q, Pl, nb, span]
-
-    # phase 2: merge block partials (blocks may share boundary segments);
-    # ids past the segment space (base + span overhang) drop into a sink.
-    ids = jnp.minimum(base[:, :, None] + jnp.arange(span, dtype=jnp.int32),
-                      num_segments)                      # [Pl, nb, span]
-    offs = (jnp.arange(q * pl_count, dtype=jnp.int32) *
-            (num_segments + 1)).reshape(q, pl_count, 1, 1)
-    acc = seg_op(partials.ravel(), (ids[None] + offs).ravel(),
-                 num_segments=q * pl_count * (num_segments + 1))
-    return acc.reshape(q, pl_count, num_segments + 1)[:, :, :num_segments]
+        interpret=interpret)                             # [Q, Pl, nb, span]
+    nb = partials.shape[2]
+    acc = _merge_partials(
+        partials.reshape(q * pl_count, nb, span),
+        jnp.broadcast_to(ids[None], (q,) + ids.shape).reshape(
+            q * pl_count, nb, span), num_segments, combine)
+    return acc.reshape(q, pl_count, num_segments)

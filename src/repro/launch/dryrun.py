@@ -278,12 +278,11 @@ def run_graph_cell(shape_name: str, mesh_name: str, rec: dict) -> dict:
     edges = {"src": sds((n_dev, e_max), jnp.int32),
              "dst_ext": sds((n_dev, e_max), jnp.int32),
              "inbox_dst": sds((n_dev, n_dev, o_max), jnp.int32)}
-    from repro.core.compat import shard_map
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(jax.tree.map(lambda _: P("parts"), state),
-                             jax.tree.map(lambda _: P("parts"), edges)),
-                   out_specs=jax.tree.map(lambda _: P("parts"), state),
-                   check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(jax.tree.map(lambda _: P("parts"), state),
+                                 jax.tree.map(lambda _: P("parts"), edges)),
+                       out_specs=jax.tree.map(lambda _: P("parts"), state),
+                       check_vma=False)
     jitted = jax.jit(fn)
     lowered = jitted.lower(state, edges)
     rec["lower_s"] = round(time.time() - t0, 2)

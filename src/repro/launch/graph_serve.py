@@ -47,6 +47,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 
 def _percentile(vals, p: float) -> float:
     if not len(vals):
@@ -1259,6 +1261,7 @@ def main(argv=None) -> int:
                          "draining the batch (composes with --mutate, "
                          "--deadline-ms, --queue-capacity, --depth-buckets)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         args.scale = min(args.scale, 8)
         args.batch = min(args.batch, 4)

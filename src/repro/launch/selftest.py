@@ -53,8 +53,8 @@ def main() -> int:
     np.testing.assert_allclose(pr_local, pr_dist, rtol=1e-5, atol=1e-8)
     print("PageRank distributed == local")
 
-    # Fused superstep path (Pallas kernel) sharded over the mesh: the
-    # compat shard_map shim + fused compute must compose.
+    # Fused superstep path (Pallas kernel) sharded over the mesh:
+    # shard_map + fused compute must compose.
     fused = DistributedBSPEngine(pg, mesh, fused=True)
     state_b, _ = fused.execute(BFS_PROGRAM,
                                batch_state({"level": jnp.asarray(level0)}))

@@ -24,6 +24,8 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
+from jax.errors import JaxRuntimeError
+
 from repro.checkpoint.manager import CheckpointManager
 
 
@@ -39,23 +41,9 @@ class ExchangeCorruption(WorkerFailure):
     death at the same superstep."""
 
 
-def _xla_error_types() -> tuple:
-    types = []
-    try:  # jaxlib's runtime error (device OOM, donated-buffer reuse, ...)
-        from jax.errors import JaxRuntimeError
-        types.append(JaxRuntimeError)
-    except ImportError:
-        pass
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        types.append(XlaRuntimeError)
-    except ImportError:
-        pass
-    return tuple(types)
-
-
-#: Errors worth a restart: injected/real worker faults + XLA runtime errors.
-RETRYABLE_EXCEPTIONS: tuple = (WorkerFailure,) + _xla_error_types()
+#: Errors worth a restart: injected/real worker faults + XLA runtime errors
+#: (device OOM, donated-buffer reuse, ...).
+RETRYABLE_EXCEPTIONS: tuple = (WorkerFailure, JaxRuntimeError)
 
 
 @dataclasses.dataclass

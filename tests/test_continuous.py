@@ -276,7 +276,9 @@ def test_execute_facade_routes_all_modes(pg):
 
     eng = BSPEngine(pg)
     state = {"level": multi_source_state(pg, [1, 2])}
-    want_state, want_steps = eng._run_batched(BFS_PROGRAM, dict(state))
+    edges = eng._edges_or_none(BFS_PROGRAM)
+    want_state, want_steps = eng._run_batched(BFS_PROGRAM, edges,
+                                              dict(state))
     got_state, got_steps = eng.execute(BFS_PROGRAM, dict(state))
     np.testing.assert_array_equal(np.asarray(got_state["level"]),
                                   np.asarray(want_state["level"]))
@@ -290,7 +292,7 @@ def test_execute_facade_routes_all_modes(pg):
                                   np.asarray(want_steps))
 
     # fixed-step mode (num_steps=) routes to _run_fixed_batched
-    want = eng._run_fixed_batched(BFS_PROGRAM, 3, dict(state))
+    want = eng._run_fixed_batched(BFS_PROGRAM, 3, edges, dict(state))
     got = eng.execute(BFS_PROGRAM, dict(state), num_steps=3)
     np.testing.assert_array_equal(np.asarray(got["level"]),
                                   np.asarray(want["level"]))

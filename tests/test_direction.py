@@ -186,6 +186,35 @@ def test_bottomup_early_exit_exact_for_uniform_frontier():
     assert np.asarray(s1).sum() < np.asarray(s0).sum()
 
 
+@pytest.mark.parametrize("semiring", ["min", "min_plus"])
+def test_bottomup_slot_tiling_matches_one_block(semiring):
+    """Splitting the slot axis over the grid (a batch too wide for one VMEM
+    slot block) keeps the row min bitwise and the early-exit counts equal
+    to a one-block scan of the same rows."""
+    from repro.kernels import ell_spmv as ell
+    from repro.kernels.ops import bottomup_scan_op
+
+    rng = np.random.default_rng(11)
+    v, kmax, nx, q = 40, 72, 64, 32
+    assert ell.slot_block(q, kmax, 512) < kmax <= ell.slot_block(1, kmax,
+                                                                 512)
+    col = rng.integers(0, nx, (v, kmax)).astype(np.int32)
+    col[rng.random((v, kmax)) < 0.3] = nx
+    kreal = (col != nx).sum(axis=1).astype(np.int32)
+    val = (rng.uniform(0, 1, (v, kmax)).astype(np.float32)
+           if semiring == "min_plus" else None)
+    x = np.full((q, nx + 1), np.inf, np.float32)
+    x[:, :nx][rng.random((q, nx)) < 0.1] = 3.0
+    y, s = bottomup_scan_op(col, val, x, kreal, semiring=semiring,
+                            early_exit=True, interpret=True)
+    for i in (0, q - 1):            # Q=1 fits one slot block
+        y1, s1 = bottomup_scan_op(col, val, x[i:i + 1], kreal,
+                                  semiring=semiring, early_exit=True,
+                                  interpret=True)
+        np.testing.assert_array_equal(np.asarray(y)[i], np.asarray(y1)[0])
+        np.testing.assert_array_equal(np.asarray(s)[i], np.asarray(s1)[0])
+
+
 def test_uniform_frontier_flags():
     """BFS declares the uniform frontier (early-exit licence); CC and
     SSSP frontiers carry distinct values and must not."""

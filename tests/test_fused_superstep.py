@@ -119,18 +119,21 @@ def test_span_limit_respects_vmem_budget():
 
 def test_vmem_budget_fallback_parity():
     """span fits max_span but busts the [block_e, span] VMEM budget →
-    byte-gated fallback, identical results."""
+    byte-gated fallback, identical results.  Spans count distinct
+    segments per block (≤ block_e), so only a wide block busts it."""
     g = G.rmat(SCALE, 4, seed=13)
     pg = PT.partition(g, PARTS, PT.HIGH)
-    blk = PT.build_block_metadata(pg.fwd, block_e=1024)
-    from repro.kernels.ops import fused_span_limit
-    if blk.span <= fused_span_limit(1024, "min"):
+    blk = PT.build_block_metadata(pg.fwd, block_e=4096)
+    from repro.kernels.ops import KERNEL_PATHS, fused_span_limit
+    if blk.span <= fused_span_limit(4096, "min"):
         pytest.skip("graph too benign to bust the budget")
     ref = BSPEngine(pg, **INTERP)
-    fus = BSPEngine(pg, fused=True, block_e=1024, **INTERP)
+    fus = BSPEngine(pg, fused=True, block_e=4096, **INTERP)
+    before = KERNEL_PATHS[("fused_superstep", "xla")]
     lr, _ = bfs(ref, 0)
     lf, _ = bfs(fus, 0)
     np.testing.assert_array_equal(lr, lf)
+    assert KERNEL_PATHS[("fused_superstep", "xla")] > before
 
 
 def test_fallback_engine_matches_for_weighted_min():
@@ -153,9 +156,12 @@ def test_block_metadata_invariants():
     blk = PT.build_block_metadata(pg.fwd, block_e=256)
     assert blk.e_pad % blk.block_e == 0
     assert blk.span % 128 == 0 and blk.span >= blk.span_req
-    # local offsets reconstruct dst_ext for every real edge
+    # each block's id table, indexed by the local ranks, reconstructs
+    # dst_ext for every real edge
     nb = blk.num_blocks
-    ids = (np.repeat(blk.base, blk.block_e, axis=1) + blk.local)
+    ids = np.take_along_axis(
+        blk.ids, blk.local.reshape(pg.num_parts, nb, blk.block_e),
+        axis=2).reshape(pg.num_parts, -1)
     e_max = pg.fwd.e_max
     real = blk.mask[:, :e_max].astype(bool)
     np.testing.assert_array_equal(ids[:, :e_max][real],

@@ -89,6 +89,56 @@ def test_ell_spmv_property_sum(v, kmax, seed):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("semiring", ["plus_times", "min_plus", "min"])
+def test_ell_spmv_slot_tiling_matches_ref(semiring):
+    """A batch too wide for one VMEM slot block splits the slot axis over
+    the grid; the accumulated rows still match the oracle."""
+    from repro.kernels import ell_spmv as ell
+
+    q, v, kmax = 32, 300, 72
+    assert ell.slot_block(q, kmax, 512) < kmax
+    rng = np.random.default_rng(3)
+    col = rng.integers(0, v + 1, size=(v, kmax)).astype(np.int32)
+    mul_ident = ell.SEMIRINGS[semiring][2]
+    val = np.where(col == v, mul_ident,
+                   rng.uniform(0.5, 2.0, size=(v, kmax))).astype(np.float32)
+    ident = ell.SEMIRINGS[semiring][1]
+    x = np.concatenate([rng.uniform(0, 10, size=(q, v)),
+                        np.full((q, 1), ident)], axis=1).astype(np.float32)
+    got = np.asarray(ops.ell_spmv_op(col, val, x, semiring=semiring,
+                                     **INTERP))
+    g = x[:, col]                                        # [Q, V, K]
+    if semiring == "plus_times":
+        want = (g * val[None]).sum(axis=2)
+    elif semiring == "min_plus":
+        want = (g + val[None]).min(axis=2)
+    else:
+        want = g.min(axis=2)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_kernel_paths_record_the_compute_path():
+    """Every kernel call site counts the path its trace took; a program
+    without an EdgeMessage on a fused engine counts as that backend's XLA
+    path."""
+    import dataclasses
+
+    from repro.algorithms.bfs import BFS_PROGRAM, bfs, multi_source_state
+    from repro.core import partition as PT
+    from repro.core.bsp import BSPEngine
+
+    pg = PT.partition(G.rmat(7, 4, seed=2), 2, PT.RAND)
+    kernel0 = ops.KERNEL_PATHS[("fused_superstep", "interpret")]
+    bfs(BSPEngine(pg, backend="fused", **INTERP), 0)
+    assert ops.KERNEL_PATHS[("fused_superstep", "interpret")] > kernel0
+
+    plain = dataclasses.replace(BFS_PROGRAM, edge_msg=None)
+    xla0 = ops.KERNEL_PATHS[("fused", "xla")]
+    BSPEngine(pg, backend="fused", **INTERP).execute(
+        plain, {"level": jnp.asarray(multi_source_state(pg, [0]))})
+    assert ops.KERNEL_PATHS[("fused", "xla")] > xla0
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

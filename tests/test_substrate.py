@@ -280,3 +280,34 @@ class TestOptim:
             e = g + e - deq
             w = w - 0.05 * deq
         assert np.abs(w).max() < 0.1
+
+
+# ---------------------------------------------------------------------------
+# persistent compile cache location (repro.compile_cache)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir_honours_env(monkeypatch, env_dir):
+    """The env var is left to JAX untouched; without it the cache sits at
+    the fixed in-repo path."""
+    import pathlib
+
+    from repro.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            assert got == str(REPO_CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == got
+            assert REPO_CACHE_DIR == (pathlib.Path(__file__).resolve()
+                                      .parents[1] / ".jax_cache")
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

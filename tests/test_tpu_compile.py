@@ -1,0 +1,137 @@
+"""Compile rehearsals of every graph kernel for a TPU v5e, without the chip.
+
+Each test compiles one Pallas kernel for a *described* v5e chip (the TPU
+compiler is installed even where no chip is attached) at the shapes
+``chip_smoke.py`` drives on the real chip, and asserts the Mosaic kernel
+made it into the executable (``tpu_custom_call``).  What interpret mode
+cannot see — (8, 128) block tiling, ops Mosaic cannot lower, scoped-VMEM
+overflow — fails here at no chip time.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library, and every test worker imports
+this file.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.algorithms.bfs import BFS_PROGRAM
+from repro.algorithms.pagerank import make_pagerank_program
+from repro.kernels import bottomup, dense_spmv, ell_spmv, fused_superstep
+from repro.kernels import outbox_reduce
+
+# chip_smoke.py's fused phase: RMAT scale 18, edge factor 16, 4 HIGH
+# partitions — v_max 249,592 (the low-degree partition) padded to whole
+# (8, 128) tiles, 1,025 edge blocks of 1,024 padded to whole steps of 8.
+FUSED_V_PAD = 250_880
+FUSED_E_PAD = 1_032 * 1_024
+FUSED_SPAN = 1_024
+BFS_Q = 4
+# its hybrid phase: uniform scale 18 (ELL in-degree ≤ 36) with a 2,048
+# vertex dense block, and the four-chip boundary leg of the same graph.
+ELL_V = 1 << 18
+ELL_K = 40
+DENSE_K = 2_048
+OUTBOX_X_PAD = 90_112
+OUTBOX_E_PAD = 3_136 * 256
+OUTBOX_SPAN = 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no topology"
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+F32, I32 = jnp.float32, jnp.int32
+
+
+@pytest.mark.parametrize("alg", ["bfs_min", "pagerank_sum"])
+def test_fused_superstep_compiles(one_chip, alg):
+    if alg == "bfs_min":
+        program, q = BFS_PROGRAM, BFS_Q
+    else:
+        program, q = make_pagerank_program(1 << 18), 1
+    spec = program.edge_msg
+
+    def msg_fn(vals, weight, scals):            # as core/bsp._compute_fused
+        return spec.fn(dict(zip(spec.gather, vals)), weight, scals[0],
+                       dict(zip(spec.consts, scals[1:])))
+
+    keys, combine = len(spec.gather), program.combine
+    edge = ((4, FUSED_E_PAD), I32)
+    _compile(lambda v, s, a, b, c: fused_superstep.fused_superstep_blocks(
+        v, s, a, b, c, None, msg_fn=msg_fn, combine=combine,
+        span=FUSED_SPAN, block_e=1_024),
+        one_chip, ((q, 4, keys, FUSED_V_PAD), F32), ((q, 4, 1), F32),
+        edge, edge, edge)
+
+
+@pytest.mark.parametrize("combine,q", [("min", BFS_Q), ("sum", 1)])
+def test_outbox_reduce_compiles(one_chip, combine, q):
+    edge = ((OUTBOX_E_PAD,), I32)
+    _compile(lambda x, a, b, c: outbox_reduce.outbox_reduce_blocks(
+        x, a, b, c, None, combine=combine, span=OUTBOX_SPAN, block_e=256),
+        one_chip, ((q, OUTBOX_X_PAD), F32), edge, edge, edge)
+
+
+@pytest.mark.parametrize("semiring", ["plus_times", "min_plus", "min"])
+def test_ell_spmv_compiles(one_chip, semiring):
+    q = 1 if semiring == "plus_times" else BFS_Q
+    bk = ell_spmv.slot_block(q, ELL_K, 512)
+    if semiring == "min":
+        fn = lambda g: ell_spmv.ell_spmv(  # noqa: E731
+            g, None, semiring=semiring, block_v=512, block_k=bk)
+        _compile(fn, one_chip, ((q, ELL_K, ELL_V), F32))
+    else:
+        fn = lambda g, v: ell_spmv.ell_spmv(  # noqa: E731
+            g, v, semiring=semiring, block_v=512, block_k=bk)
+        _compile(fn, one_chip, ((q, ELL_K, ELL_V), F32),
+                 ((ELL_K, ELL_V), F32))
+
+
+@pytest.mark.parametrize("semiring", ["min", "min_plus"])
+def test_bottomup_scan_compiles(one_chip, semiring):
+    bk = ell_spmv.slot_block(BFS_Q, ELL_K, 512)
+    g = ((BFS_Q, ELL_K, ELL_V), F32)
+    kreal = ((1, ELL_V), I32)
+    if semiring == "min":
+        fn = lambda g, k: bottomup.bottomup_scan(  # noqa: E731
+            g, None, k, semiring=semiring, early_exit=True, block_v=512,
+            block_k=bk)
+        _compile(fn, one_chip, g, kreal)
+    else:
+        fn = lambda g, v, k: bottomup.bottomup_scan(  # noqa: E731
+            g, v, k, semiring=semiring, early_exit=True, block_v=512,
+            block_k=bk)
+        _compile(fn, one_chip, g, ((ELL_K, ELL_V), F32), kreal)
+
+
+@pytest.mark.parametrize("op", ["dense_spmv", "dense_spmv_minplus"])
+def test_dense_spmv_compiles(one_chip, op):
+    fn = getattr(dense_spmv, op)
+    _compile(lambda x, a: fn(x, a, block_n=256, block_k=256), one_chip,
+             ((BFS_Q, DENSE_K), F32), ((DENSE_K, DENSE_K), F32))
