@@ -121,6 +121,13 @@ def ranks_match(got, want) -> bool:
                 and np.allclose(got, want, rtol=1e-4, atol=1e-4 / n))
 
 
+def max_rel_err(got, want) -> float:
+    import numpy as np
+
+    return float(np.max(np.abs(got - want) / np.maximum(np.abs(want),
+                                                         1e-30)))
+
+
 def paths_since(before: collections.Counter) -> dict:
     from repro.kernels.ops import KERNEL_PATHS
 
@@ -162,7 +169,8 @@ def run_pagerank(engine, g, clock, backend: str, base: dict) -> None:
 
     with Timed(clock) as t:
         rank = pagerank(engine, PR_ITERS)
-    ok = ranks_match(rank, pagerank_reference(g, PR_ITERS))
+    want = pagerank_reference(g, PR_ITERS)
+    ok = ranks_match(rank, want)
     custom_call = None
     if backend != "reference":
         program = make_pagerank_program(engine.pg.num_vertices)
@@ -170,7 +178,8 @@ def run_pagerank(engine, g, clock, backend: str, base: dict) -> None:
             engine, program, PR_ITERS, batch_state(initial_state(engine.pg)))
     report(**base, alg="pagerank", supersteps=PR_ITERS,
            compile_s=t.compile, run_s=t.wall - t.compile, wall_s=t.wall,
-           match=ok, tpu_custom_call=custom_call)
+           match=ok, max_rel_err=max_rel_err(rank, want),
+           tpu_custom_call=custom_call)
     check(ok, f"{backend} pagerank differs from pagerank_reference")
     check(custom_call is not False,
           f"{backend} pagerank step has no tpu_custom_call")
@@ -308,10 +317,11 @@ def phase_four_chips(clock, seed: int) -> None:
 
         with Timed(clock) as t:
             rank = pagerank_distributed(dist, PR_ITERS)
-        ok = ranks_match(rank, pagerank(single, PR_ITERS))
+        want = pagerank(single, PR_ITERS)
+        ok = ranks_match(rank, want)
         report(**base, alg="pagerank", supersteps=PR_ITERS,
                compile_s=t.compile, run_s=t.wall - t.compile, wall_s=t.wall,
-               match_single_device=ok)
+               match_single_device=ok, max_rel_err=max_rel_err(rank, want))
         check(ok, f"distributed {backend} pagerank differs from single "
                   f"device")
         paths = paths_since(before)

@@ -9,7 +9,7 @@ SpMV-as-GEMM beats gather-based SpMV (see
 bitmap (§6.3.2) maps to the VMEM residency of the value slice ``x``: the
 x-block is re-used across all output tiles of a row stripe.
 
-Computes ``y[M, N] = x[M, K] @ a[K, N]`` where ``a`` is the (bf16) dense
+Computes ``y[M, N] = x[M, K] @ a[K, N]`` where ``a`` is the dense
 adjacency block of the high-degree partition, ``x`` carries the per-vertex
 values (rank / frontier levels / multi-source batch on the M axis).
 
@@ -40,8 +40,11 @@ def _dense_spmv_kernel(x_ref, a_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # MXU matmul with f32 accumulation (bf16 inputs are the target dtype).
+    # MXU matmul with f32 accumulation.  HIGHEST: Mosaic's default
+    # contraction precision rounds f32 operands to bf16 (~3 significant
+    # digits), which PageRank's per-vertex values do not survive.
     o_ref[...] += jnp.dot(x_ref[...], a_ref[...],
+                          precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
 
 
@@ -51,7 +54,7 @@ def dense_spmv(x: jax.Array, a: jax.Array, *, block_n: int = 256,
                block_k: int = 256, interpret: bool = False) -> jax.Array:
     """``y = x @ a`` with explicit VMEM tiling.
 
-    x: [M, K] (f32 or bf16), a: [K, N] (bf16 target). M is the value-channel
+    x: [M, K], a: [K, N], both f32. M is the value-channel
     axis (1 for plain SpMV, padded to 8 sublanes by ops.py).
     """
     m, k = x.shape

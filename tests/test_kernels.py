@@ -46,6 +46,18 @@ def test_dense_spmv_property(m, k, n, seed):
                                rtol=1e-4, atol=1e-4)
 
 
+def test_dense_spmv_asks_full_f32_precision():
+    # Mosaic's default contraction rounds f32 operands to bf16 on the MXU;
+    # interpret mode cannot show that, so check what the kernel asks for.
+    from repro.kernels import dense_spmv
+
+    x = jnp.ones((8, 128), jnp.float32)
+    a = jnp.ones((128, 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x, a: dense_spmv.dense_spmv(
+        x, a, block_n=128, block_k=128, **INTERP))(x, a)
+    assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" in str(jaxpr)
+
+
 # ---------------------------------------------------------------------------
 # ell_spmv
 # ---------------------------------------------------------------------------
