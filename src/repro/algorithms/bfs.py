@@ -14,6 +14,7 @@ from typing import Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.bsp import (MIN, BSPEngine, EdgeMessage, IncrementalForm,
                             VertexProgram, gather_src)
 from repro.core.graph import CSRGraph
@@ -40,7 +41,10 @@ def multi_source_state(pg: PartitionedGraph, sources: Sequence[int],
 
 def gather_batch(pg: PartitionedGraph, per_part: np.ndarray) -> np.ndarray:
     """Collect a [Q, P, v_max] batched state into global [Q, n] order."""
-    return np.stack([pg.gather_global(row) for row in np.asarray(per_part)])
+    with obs.span(obs.FETCH):
+        with obs.span(obs.WAIT):
+            per_part = np.asarray(per_part)
+        return np.stack([pg.gather_global(row) for row in per_part])
 
 
 def _edge_fn(state, src, weight, step):
@@ -139,9 +143,9 @@ def bfs_batched(engine: BSPEngine,
     Returns (levels [Q, n], per-query supersteps [Q]).
     """
     pg = engine.pg
-    level0 = multi_source_state(pg, sources)
-    state, steps = engine.execute(BFS_PROGRAM,
-                                  {"level": jnp.asarray(level0)})
+    with obs.span(obs.STATE_INIT):
+        level0 = jnp.asarray(multi_source_state(pg, sources))
+    state, steps = engine.execute(BFS_PROGRAM, {"level": level0})
     return gather_batch(pg, state["level"]), np.asarray(steps)
 
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.bsp import (SUM, BSPEngine, EdgeMessage, VertexProgram,
                             batch_state, gather_src, unbatch_state)
 from repro.kernels import ops as kops
@@ -74,10 +75,14 @@ def pagerank(engine: BSPEngine, num_iterations: int = 20,
              damping: float = DAMPING) -> np.ndarray:
     pg = engine.pg
     program = make_pagerank_program(pg.num_vertices, damping)
-    state = unbatch_state(engine.execute(program,
-                                         batch_state(initial_state(pg)),
+    with obs.span(obs.STATE_INIT):
+        state0 = batch_state(initial_state(pg))
+    state = unbatch_state(engine.execute(program, state0,
                                          num_steps=num_iterations))
-    return pg.gather_global(np.asarray(state["rank"]))
+    with obs.span(obs.FETCH):
+        with obs.span(obs.WAIT):
+            rank = np.asarray(state["rank"])
+        return pg.gather_global(rank)
 
 
 def make_personalized_pagerank_program(damping: float = DAMPING,

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import obs
 from repro.runtime import chaos
 from repro.core.partition import (BlockMetadata, EdgeArrays, PartitionedGraph,
                                   build_block_metadata)
@@ -265,11 +266,14 @@ def _direction_select(want: Array, run_push, run_pull, x):
         return (jnp.where(sel.reshape(shape), y_l, y_p),
                 jnp.where(sel, zero, cp), jnp.where(sel, sl, zero))
 
-    return jax.lax.cond(
-        jnp.all(want == _DIR_PUSH), run_push,
-        lambda x: jax.lax.cond(jnp.all(want == _DIR_PULL),
-                               run_pull, mixed, x),
-        x)
+    # The branches' own phases nest inside: what they leave unscoped (the
+    # work counters, the per-query select) is the direction's.
+    with obs.phase("bsp.direction"):
+        return jax.lax.cond(
+            jnp.all(want == _DIR_PUSH), run_push,
+            lambda x: jax.lax.cond(jnp.all(want == _DIR_PULL),
+                                   run_pull, mixed, x),
+            x)
 
 
 def _dopt_want(forced: Optional[int], density: Array, unvisited: Array,
@@ -350,17 +354,21 @@ def _superstep_hybrid(program: VertexProgram, cfg: _HybridCfg, arrs: dict,
     track = dopt is not None and "push_src" in arrs and "ell_kreal" in arrs
     q = state[spec.gather[0]].shape[0]
     n = cfg.num_vertices
-    vals = {k: state[k].astype(jnp.float32).reshape(q, -1)[:, arrs["slot"]]
-            for k in spec.gather}           # [Q, n] in hybrid id space
-    # Per-partition scalar consts are replicated across partitions in the
-    # single-device engines; the global compute reads partition 0's copy
-    # (shaped [Q, 1] so they broadcast against the [Q, n] values).
-    consts = {c: state[c][:, :1].astype(jnp.float32) for c in spec.consts}
-    w_ident = None
-    if spec.use_weight:
-        w_ident = jnp.float32(0.0 if spec.weight_op == "add" else 1.0)
-    x = spec.fn(vals, w_ident, step.astype(jnp.float32),
-                consts).astype(jnp.float32)              # [Q, n]
+    with obs.phase("bsp.layout"):
+        vals = {k: state[k].astype(jnp.float32).reshape(q, -1)[:,
+                                                               arrs["slot"]]
+                for k in spec.gather}       # [Q, n] in hybrid id space
+        # Per-partition scalar consts are replicated across partitions in
+        # the single-device engines; the global compute reads partition
+        # 0's copy (shaped [Q, 1] so they broadcast against the [Q, n]
+        # values).
+        consts = {c: state[c][:, :1].astype(jnp.float32)
+                  for c in spec.consts}
+        w_ident = None
+        if spec.use_weight:
+            w_ident = jnp.float32(0.0 if spec.weight_op == "add" else 1.0)
+        x = spec.fn(vals, w_ident, step.astype(jnp.float32),
+                    consts).astype(jnp.float32)          # [Q, n]
 
     def pull(x):
         return hybrid_spmv(arrs["dense"], arrs["ell_col"], arrs["ell_val"],
@@ -373,28 +381,31 @@ def _superstep_hybrid(program: VertexProgram, cfg: _HybridCfg, arrs: dict,
             # e.g. the dynamic engine's spare push capacity) gather the
             # ⊕-identity sink and reduce into a discarded segment, so
             # padding is inert by construction.
-            x_ext = jnp.concatenate(
-                [x, jnp.full((q, 1), ident, x.dtype)], axis=1)
-            msgs = x_ext[:, arrs["push_src"]]            # [Q, E]
-            if "push_w" in arrs:
-                msgs = msgs + arrs["push_w"]
-            offs = (jnp.arange(q, dtype=jnp.int32) * (n + 1))[:, None]
-            y = jax.ops.segment_min(msgs.ravel(),
-                                    (arrs["push_dst"][None] + offs).ravel(),
-                                    num_segments=q * (n + 1))
-            return y.reshape(q, n + 1)[:, :n], msgs
+            with obs.phase("bsp.gather"):
+                x_ext = jnp.concatenate(
+                    [x, jnp.full((q, 1), ident, x.dtype)], axis=1)
+                msgs = x_ext[:, arrs["push_src"]]        # [Q, E]
+                if "push_w" in arrs:
+                    msgs = msgs + arrs["push_w"]
+            with obs.phase("bsp.reduce"):
+                offs = (jnp.arange(q, dtype=jnp.int32) * (n + 1))[:, None]
+                y = jax.ops.segment_min(
+                    msgs.ravel(), (arrs["push_dst"][None] + offs).ravel(),
+                    num_segments=q * (n + 1))
+                return y.reshape(q, n + 1)[:, :n], msgs
 
         # Per-query frontier density vs the fitted crossover, guarded by
         # the unvisited mass (still-⊕-identity vertices never early-exit
         # a pull scan), picks the direction — a perf choice only; both
         # directions are exact for min combines, and each query votes for
         # itself (satellite 1).
-        nf = jnp.float32(max(n, 1))
-        density = jnp.sum((x != ident).astype(jnp.float32), axis=1) / nf
-        unvisited = jnp.sum(
-            (vals[spec.gather[0]] == ident).astype(jnp.float32), axis=1) / nf
-        want = _dopt_want(cfg.forced if track else None, density, unvisited,
-                          cfg.pull_threshold)
+        with obs.phase("bsp.direction"):
+            nf = jnp.float32(max(n, 1))
+            density = jnp.sum((x != ident).astype(jnp.float32), axis=1) / nf
+            unvisited = jnp.sum((vals[spec.gather[0]] == ident).astype(
+                jnp.float32), axis=1) / nf
+            want = _dopt_want(cfg.forced if track else None, density,
+                              unvisited, cfg.pull_threshold)
 
         if track:
             e_dense = jnp.full((q,), cfg.e_dense, jnp.int32)
@@ -406,8 +417,10 @@ def _superstep_hybrid(program: VertexProgram, cfg: _HybridCfg, arrs: dict,
 
             # Under the uniform licence a row already holding a value is
             # final — a sequential bottom-up skips it (zero scanned slots).
-            skip = ((vals[spec.gather[0]] != ident) if cfg.uniform
-                    else None)
+            skip = None
+            if cfg.uniform:
+                with obs.phase("bsp.direction"):
+                    skip = vals[spec.gather[0]] != ident
 
             def run_pull(x):
                 y, scanned = hybrid_spmv_scan(
@@ -419,7 +432,8 @@ def _superstep_hybrid(program: VertexProgram, cfg: _HybridCfg, arrs: dict,
 
             y, cnt_push, cnt_pull = _direction_select(
                 want, run_push, run_pull, x)
-            dopt = _dopt_fold(dopt, want, cnt_push + cnt_pull)
+            with obs.phase("bsp.direction"):
+                dopt = _dopt_fold(dopt, want, cnt_push + cnt_pull)
         else:
             zero = jnp.zeros((q,), jnp.int32)
             y, _, _ = _direction_select(
@@ -429,13 +443,16 @@ def _superstep_hybrid(program: VertexProgram, cfg: _HybridCfg, arrs: dict,
     else:
         y = pull(x)
 
-    y_ext = jnp.concatenate([y, jnp.full((q, 1), ident, y.dtype)], axis=1)
-    acc = y_ext[:, arrs["hid"]]             # back to [Q, P, v_max] layout
-    new_state, finished = jax.vmap(program.apply_fn,
-                                   in_axes=(0, 0, None))(state, acc, step)
-    if dopt is not None:
-        new_state = dict(new_state, **dopt)
-    return new_state, all_finished(finished)
+    with obs.phase("bsp.layout"):
+        y_ext = jnp.concatenate([y, jnp.full((q, 1), ident, y.dtype)],
+                                axis=1)
+        acc = y_ext[:, arrs["hid"]]         # back to [Q, P, v_max] layout
+    with obs.phase("bsp.apply"):
+        new_state, finished = jax.vmap(program.apply_fn,
+                                       in_axes=(0, 0, None))(state, acc, step)
+        if dopt is not None:
+            new_state = dict(new_state, **dopt)
+        return new_state, all_finished(finished)
 
 
 def _superstep_hybrid_dist(program: VertexProgram, shd, arrs: dict,
@@ -480,17 +497,20 @@ def _superstep_hybrid_dist(program: VertexProgram, shd, arrs: dict,
     state, dopt = _dopt_strip(state)
     track = dopt is not None and "push_src" in arrs and "ell_kreal" in arrs
     q = state[spec.gather[0]].shape[0]
-    vals = {k: state[k].astype(jnp.float32).reshape(q, -1)[:, slot]
-            for k in spec.gather}                       # [Q, n_max]
-    consts = {c: state[c][:, :1].astype(jnp.float32) for c in spec.consts}
-    w_ident = None
-    if spec.use_weight:
-        w_ident = jnp.float32(0.0 if spec.weight_op == "add" else 1.0)
-    x = spec.fn(vals, w_ident, step.astype(jnp.float32),
-                consts).astype(jnp.float32)             # [Q, n_max]
-    n_vert = arrs["n_vert"][0]
-    vmask = jnp.arange(shd.n_max, dtype=jnp.int32) < n_vert
-    x = jnp.where(vmask[None], x, ident)  # pad hybrid ids never contribute
+    with obs.phase("bsp.layout"):
+        vals = {k: state[k].astype(jnp.float32).reshape(q, -1)[:, slot]
+                for k in spec.gather}                   # [Q, n_max]
+        consts = {c: state[c][:, :1].astype(jnp.float32)
+                  for c in spec.consts}
+        w_ident = None
+        if spec.use_weight:
+            w_ident = jnp.float32(0.0 if spec.weight_op == "add" else 1.0)
+        x = spec.fn(vals, w_ident, step.astype(jnp.float32),
+                    consts).astype(jnp.float32)         # [Q, n_max]
+        n_vert = arrs["n_vert"][0]
+        vmask = jnp.arange(shd.n_max, dtype=jnp.int32) < n_vert
+        # pad hybrid ids never contribute
+        x = jnp.where(vmask[None], x, ident)
 
     def pull(xv):
         return hybrid_spmv(arrs["dense"][0], arrs["ell_col"][0],
@@ -499,36 +519,42 @@ def _superstep_hybrid_dist(program: VertexProgram, shd, arrs: dict,
 
     if "push_src" in arrs:
         def push_msgs(xv):
-            x_ext = jnp.concatenate(
-                [xv, jnp.full((q, 1), ident, xv.dtype)], axis=1)
-            msgs = x_ext[:, arrs["push_src"][0]]        # [Q, ei]
-            if "push_w" in arrs:
-                msgs = msgs + arrs["push_w"][0]
-            offs = (jnp.arange(q, dtype=jnp.int32)
-                    * (shd.n_max + 1))[:, None]
-            y = jax.ops.segment_min(
-                msgs.ravel(), (arrs["push_dst"][0][None] + offs).ravel(),
-                num_segments=q * (shd.n_max + 1))
-            return y.reshape(q, shd.n_max + 1)[:, : shd.n_max], msgs
+            with obs.phase("bsp.gather"):
+                x_ext = jnp.concatenate(
+                    [xv, jnp.full((q, 1), ident, xv.dtype)], axis=1)
+                msgs = x_ext[:, arrs["push_src"][0]]    # [Q, ei]
+                if "push_w" in arrs:
+                    msgs = msgs + arrs["push_w"][0]
+            with obs.phase("bsp.reduce"):
+                offs = (jnp.arange(q, dtype=jnp.int32)
+                        * (shd.n_max + 1))[:, None]
+                y = jax.ops.segment_min(
+                    msgs.ravel(),
+                    (arrs["push_dst"][0][None] + offs).ravel(),
+                    num_segments=q * (shd.n_max + 1))
+                return y.reshape(q, shd.n_max + 1)[:, : shd.n_max], msgs
 
         # Per-(query, shard) frontier density vs this shard's fitted
         # crossover, guarded by the shard's unvisited mass, picks the
         # direction — each query votes for itself from the shard's own
         # frontier slice (a perf choice only; both directions are exact
         # for min combines).
-        thr = (arrs["pull_thr"][0][0, 0] if "pull_thr" in arrs
-               else pull_threshold)
-        nf = jnp.maximum(n_vert.astype(jnp.float32), 1.0)
-        density = jnp.sum((x != ident).astype(jnp.float32), axis=1) / nf
-        unvisited = jnp.sum(jnp.logical_and(
-            vals[spec.gather[0]] == ident,
-            vmask[None]).astype(jnp.float32), axis=1) / nf
-        want = _dopt_want(forced if track else None, density, unvisited, thr)
+        with obs.phase("bsp.direction"):
+            thr = (arrs["pull_thr"][0][0, 0] if "pull_thr" in arrs
+                   else pull_threshold)
+            nf = jnp.maximum(n_vert.astype(jnp.float32), 1.0)
+            density = jnp.sum((x != ident).astype(jnp.float32), axis=1) / nf
+            unvisited = jnp.sum(jnp.logical_and(
+                vals[spec.gather[0]] == ident,
+                vmask[None]).astype(jnp.float32), axis=1) / nf
+            want = _dopt_want(forced if track else None, density, unvisited,
+                              thr)
 
         if track:
-            ed = (arrs["e_dense"][0][0] if "e_dense" in arrs
-                  else jnp.int32(e_dense))
-            e_dense_q = jnp.broadcast_to(ed.astype(jnp.int32), (q,))
+            with obs.phase("bsp.direction"):
+                ed = (arrs["e_dense"][0][0] if "e_dense" in arrs
+                      else jnp.int32(e_dense))
+                e_dense_q = jnp.broadcast_to(ed.astype(jnp.int32), (q,))
 
             def run_push(xv):
                 y, msgs = push_msgs(xv)
@@ -537,7 +563,10 @@ def _superstep_hybrid_dist(program: VertexProgram, shd, arrs: dict,
 
             # Uniform licence: rows already holding a value are final and
             # charge zero scanned slots (sequential bottom-up skips them).
-            skip = ((vals[spec.gather[0]] != ident) if uniform else None)
+            skip = None
+            if uniform:
+                with obs.phase("bsp.direction"):
+                    skip = vals[spec.gather[0]] != ident
 
             def run_pull(xv):
                 y, scanned = hybrid_spmv_scan(
@@ -549,18 +578,19 @@ def _superstep_hybrid_dist(program: VertexProgram, shd, arrs: dict,
 
             y, cnt_push, cnt_pull = _direction_select(
                 want, run_push, run_pull, x)
-            cnt = cnt_push + cnt_pull
-            if shd.has_boundary:
-                # Boundary edges always run the push-style outbox reduction
-                # below, whichever way the intra step went — charge them in
-                # both directions.
-                x_ext = jnp.concatenate(
-                    [x, jnp.full((q, 1), ident, x.dtype)], axis=1)
-                live = (x_ext[:, arrs["b_src"][0]] != ident)
-                live = jnp.logical_and(
-                    live, (arrs["b_mask"][0] != 0)[None])
-                cnt = cnt + jnp.sum(live.astype(jnp.int32), axis=1)
-            dopt = _dopt_fold(dopt, want, cnt)
+            with obs.phase("bsp.direction"):
+                cnt = cnt_push + cnt_pull
+                if shd.has_boundary:
+                    # Boundary edges always run the push-style outbox
+                    # reduction below, whichever way the intra step went —
+                    # charge them in both directions.
+                    x_ext = jnp.concatenate(
+                        [x, jnp.full((q, 1), ident, x.dtype)], axis=1)
+                    live = (x_ext[:, arrs["b_src"][0]] != ident)
+                    live = jnp.logical_and(
+                        live, (arrs["b_mask"][0] != 0)[None])
+                    cnt = cnt + jnp.sum(live.astype(jnp.int32), axis=1)
+                dopt = _dopt_fold(dopt, want, cnt)
         else:
             zero = jnp.zeros((q,), jnp.int32)
             y, _, _ = _direction_select(
@@ -574,62 +604,73 @@ def _superstep_hybrid_dist(program: VertexProgram, shd, arrs: dict,
     seg = shd.scatter_segments
     racc = None
     if shd.has_boundary:
-        x_ext = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)],
-                                axis=1)
-        outbox = outbox_reduce_op(
-            x_ext, arrs["b_src"][0], arrs["b_local"][0], arrs["b_mask"][0],
-            arrs["b_ids"][0], arrs.get("b_weight", [None])[0],
-            num_slots=shd.num_slots, combine=program.combine,
-            weight_op=spec.weight_op if spec.use_weight else None,
-            span=shd.b_span, block_e=shd.b_block,
-            interpret=interpret)                        # [Q, num_slots]
-        obox_ext = jnp.concatenate(
-            [outbox, jnp.full((q, 1), ident, outbox.dtype)], axis=1)
-        rvals, rids = [], []
-        if shd.has_remote:
-            send = obox_ext[:, arrs["send_idx"][0]]     # [Q, S, w]
-            if guard is not None and n_shards is not None and n_shards > 1:
-                # Checksummed compact exchange: one reduction tag per
-                # destination shard, shipped over its own tiled all_to_all;
-                # the receiver re-tags its S/n_shards block per source.
-                blk = send.shape[1] // n_shards
-                tags = _payload_tag(
-                    send.reshape(q, n_shards, blk, -1), (0, 2, 3))
-                send = jnp.where(guard.poison > 0, _flip_wire(send), send)
-                want = jax.lax.all_to_all(
-                    tags.reshape(n_shards, 1), axis, split_axis=0,
-                    concat_axis=0, tiled=True).reshape(n_shards)
-                recv = jax.lax.all_to_all(send, axis, split_axis=1,
-                                          concat_axis=1, tiled=True)
-                got = _payload_tag(
-                    recv.reshape(q, n_shards, blk, -1), (0, 2, 3))
-                guard.add(jnp.sum((got != want).astype(jnp.int32)))
-            else:
-                recv = jax.lax.all_to_all(send, axis, split_axis=1,
-                                          concat_axis=1, tiled=True)
-            rvals.append(recv.reshape(q, -1))
-            rids.append(arrs["recv_ids"][0].reshape(-1))
-        if shd.has_local_slots:
-            rvals.append(obox_ext[:, arrs["loc_idx"][0]])
-            rids.append(arrs["loc_ids"][0])
-        if rvals:
-            ids = jnp.concatenate(rids)                 # [L], shared over Q
-            offs = (jnp.arange(q, dtype=jnp.int32) * (seg + 1))[:, None]
-            racc = seg_op(jnp.concatenate(rvals, axis=1).ravel(),
-                          (ids[None] + offs).ravel(),
-                          num_segments=q * (seg + 1))
-            racc = racc.reshape(q, seg + 1)[:, :seg]
-            racc = racc.reshape(q, pl, v_max + 1)[:, :, :v_max]
+        with obs.phase("bsp.reduce"):
+            x_ext = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)],
+                                    axis=1)
+            outbox = outbox_reduce_op(
+                x_ext, arrs["b_src"][0], arrs["b_local"][0],
+                arrs["b_mask"][0], arrs["b_ids"][0],
+                arrs.get("b_weight", [None])[0],
+                num_slots=shd.num_slots, combine=program.combine,
+                weight_op=spec.weight_op if spec.use_weight else None,
+                span=shd.b_span, block_e=shd.b_block,
+                interpret=interpret)                    # [Q, num_slots]
+        with obs.phase("bsp.exchange"):
+            obox_ext = jnp.concatenate(
+                [outbox, jnp.full((q, 1), ident, outbox.dtype)], axis=1)
+            rvals, rids = [], []
+            if shd.has_remote:
+                send = obox_ext[:, arrs["send_idx"][0]]  # [Q, S, w]
+                if (guard is not None and n_shards is not None
+                        and n_shards > 1):
+                    # Checksummed compact exchange: one reduction tag per
+                    # destination shard, shipped over its own tiled
+                    # all_to_all; the receiver re-tags its S/n_shards
+                    # block per source.
+                    blk = send.shape[1] // n_shards
+                    tags = _payload_tag(
+                        send.reshape(q, n_shards, blk, -1), (0, 2, 3))
+                    send = jnp.where(guard.poison > 0, _flip_wire(send),
+                                     send)
+                    want = jax.lax.all_to_all(
+                        tags.reshape(n_shards, 1), axis, split_axis=0,
+                        concat_axis=0, tiled=True).reshape(n_shards)
+                    recv = jax.lax.all_to_all(send, axis, split_axis=1,
+                                              concat_axis=1, tiled=True)
+                    got = _payload_tag(
+                        recv.reshape(q, n_shards, blk, -1), (0, 2, 3))
+                    guard.add(jnp.sum((got != want).astype(jnp.int32)))
+                else:
+                    recv = jax.lax.all_to_all(send, axis, split_axis=1,
+                                              concat_axis=1, tiled=True)
+                rvals.append(recv.reshape(q, -1))
+                rids.append(arrs["recv_ids"][0].reshape(-1))
+            if shd.has_local_slots:
+                rvals.append(obox_ext[:, arrs["loc_idx"][0]])
+                rids.append(arrs["loc_ids"][0])
+            if rvals:
+                ids = jnp.concatenate(rids)             # [L], shared over Q
+                offs = (jnp.arange(q, dtype=jnp.int32)
+                        * (seg + 1))[:, None]
+                racc = seg_op(jnp.concatenate(rvals, axis=1).ravel(),
+                              (ids[None] + offs).ravel(),
+                              num_segments=q * (seg + 1))
+                racc = racc.reshape(q, seg + 1)[:, :seg]
+                racc = racc.reshape(q, pl, v_max + 1)[:, :, :v_max]
 
-    y_ext = jnp.concatenate([y, jnp.full((q, 1), ident, y.dtype)], axis=1)
-    acc = y_ext[:, arrs["hid"][0]]                      # [Q, pl, v_max]
+    with obs.phase("bsp.layout"):
+        y_ext = jnp.concatenate([y, jnp.full((q, 1), ident, y.dtype)],
+                                axis=1)
+        acc = y_ext[:, arrs["hid"][0]]                  # [Q, pl, v_max]
     if racc is not None:
-        acc = _COMBINE[program.combine](acc, racc)
-    new_state, finished = jax.vmap(program.apply_fn,
-                                   in_axes=(0, 0, None))(state, acc, step)
-    if dopt is not None:
-        new_state = dict(new_state, **dopt)
-    return new_state, all_finished(finished)
+        with obs.phase("bsp.exchange"):
+            acc = _COMBINE[program.combine](acc, racc)
+    with obs.phase("bsp.apply"):
+        new_state, finished = jax.vmap(program.apply_fn,
+                                       in_axes=(0, 0, None))(state, acc, step)
+        if dopt is not None:
+            new_state = dict(new_state, **dopt)
+        return new_state, all_finished(finished)
 
 
 def _compute_reference(dims: _Dims, program: VertexProgram, edges: dict,
@@ -640,15 +681,17 @@ def _compute_reference(dims: _Dims, program: VertexProgram, edges: dict,
     query axis runs it once per query against the *shared* edge arrays."""
     pl = edges["src"].shape[0]
     src, weight = edges["src"], edges.get("weight")
-    msgs = jax.vmap(
-        lambda st: program.edge_fn(st, src, weight, step))(state)
-    q = msgs.shape[0]
-    offs = (jnp.arange(q * pl, dtype=jnp.int32)
-            * dims.seg).reshape(q, pl, 1)
-    ids = (edges["dst_ext"][None] + offs).ravel()
-    acc = _SEGMENT_OP[program.combine](msgs.ravel(), ids,
-                                       num_segments=q * pl * dims.seg)
-    return acc.reshape(q, pl, dims.seg)
+    with obs.phase("bsp.gather"):
+        msgs = jax.vmap(
+            lambda st: program.edge_fn(st, src, weight, step))(state)
+    with obs.phase("bsp.reduce"):
+        q = msgs.shape[0]
+        offs = (jnp.arange(q * pl, dtype=jnp.int32)
+                * dims.seg).reshape(q, pl, 1)
+        ids = (edges["dst_ext"][None] + offs).ravel()
+        acc = _SEGMENT_OP[program.combine](msgs.ravel(), ids,
+                                           num_segments=q * pl * dims.seg)
+        return acc.reshape(q, pl, dims.seg)
 
 
 def _compute_fused(dims: _Dims, program: VertexProgram, edges: dict,
@@ -665,12 +708,13 @@ def _compute_fused(dims: _Dims, program: VertexProgram, edges: dict,
 
     spec = program.edge_msg
     pl = edges["src"].shape[0]
-    vstate = jnp.stack([state[k].astype(jnp.float32) for k in spec.gather],
-                       axis=2)                            # [Q, Pl, K, v_max]
-    q = vstate.shape[0]
-    cols = [jnp.broadcast_to(step.astype(jnp.float32), (q, pl))]
-    cols += [state[k].astype(jnp.float32) for k in spec.consts]
-    scal = jnp.stack(cols, axis=2)                        # [Q, Pl, 1+consts]
+    with obs.phase("bsp.gather"):
+        vstate = jnp.stack([state[k].astype(jnp.float32)
+                            for k in spec.gather], axis=2)  # [Q, Pl, K, v]
+        q = vstate.shape[0]
+        cols = [jnp.broadcast_to(step.astype(jnp.float32), (q, pl))]
+        cols += [state[k].astype(jnp.float32) for k in spec.consts]
+        scal = jnp.stack(cols, axis=2)                    # [Q, Pl, 1+consts]
 
     def msg_fn(vals, weight, scals):
         vals_d = dict(zip(spec.gather, vals))
@@ -678,12 +722,13 @@ def _compute_fused(dims: _Dims, program: VertexProgram, edges: dict,
         return spec.fn(vals_d, weight, scals[0], consts_d)
 
     weight = edges.get("weight_blk") if spec.use_weight else None
-    return fused_superstep_op(
-        msg_fn, vstate, weight, scal, edges["blk_src"], edges["blk_local"],
-        edges["blk_mask"], edges["blk_ids"], edges["dst_ext"],
-        num_segments=dims.seg, combine=program.combine, span=cfg.span,
-        block_e=cfg.block_e, max_span=cfg.max_span,
-        interpret=cfg.interpret)
+    with obs.phase("bsp.reduce"):
+        return fused_superstep_op(
+            msg_fn, vstate, weight, scal, edges["blk_src"],
+            edges["blk_local"], edges["blk_mask"], edges["blk_ids"],
+            edges["dst_ext"], num_segments=dims.seg,
+            combine=program.combine, span=cfg.span, block_e=cfg.block_e,
+            max_span=cfg.max_span, interpret=cfg.interpret)
 
 
 def _superstep(dims: _Dims, program: VertexProgram, edges: dict,
@@ -716,13 +761,15 @@ def _superstep(dims: _Dims, program: VertexProgram, edges: dict,
     if dyn is not None:
         edges = dict(edges)
         tomb = dyn["tomb"]
-        edges["dst_ext"] = jnp.where(tomb, dims.v_max, edges["dst_ext"])
         edges["inbox_dst"] = dyn["inbox_dst"]
-        if "blk_mask" in edges:
-            pad = edges["blk_mask"].shape[1] - tomb.shape[1]
-            alive = jnp.pad(jnp.logical_not(tomb), ((0, 0), (0, pad)))
-            edges["blk_mask"] = edges["blk_mask"] * alive.astype(
-                edges["blk_mask"].dtype)
+        with obs.phase("bsp.reduce"):
+            edges["dst_ext"] = jnp.where(tomb, dims.v_max,
+                                         edges["dst_ext"])
+            if "blk_mask" in edges:
+                pad = edges["blk_mask"].shape[1] - tomb.shape[1]
+                alive = jnp.pad(jnp.logical_not(tomb), ((0, 0), (0, pad)))
+                edges["blk_mask"] = edges["blk_mask"] * alive.astype(
+                    edges["blk_mask"].dtype)
 
     # -- compute: per-edge messages, reduced over extended destinations -----
     def compute_push(state, step):
@@ -739,41 +786,46 @@ def _superstep(dims: _Dims, program: VertexProgram, edges: dict,
         ident = jnp.float32(jnp.inf)
         v_max = dims.v_max
         q = state[spec.gather[0]].shape[0]
-        # Per-vertex messages; the push direction's per-edge messages are
-        # gathers of exactly these values (the reference↔fused bitwise
-        # parity already leans on edge_fn ≡ gather∘edge_msg.fn).
-        vvals = {k: state[k].astype(jnp.float32) for k in spec.gather}
-        vconsts = {c: state[c][:, :, None].astype(jnp.float32)
-                   for c in spec.consts}
-        w_ident = None
-        if spec.use_weight:
-            w_ident = jnp.float32(0.0 if spec.weight_op == "add" else 1.0)
-        xv = spec.fn(vvals, w_ident, step.astype(jnp.float32),
-                     vconsts).astype(jnp.float32)        # [Q, Pl, v_max]
-        vmask = edges["t_vmask"]
-        act = jnp.logical_and(xv != ident,
-                              vmask[None]).astype(jnp.float32)
-        nreal = jnp.maximum(jnp.sum(vmask.astype(jnp.float32)), 1.0)
-        density = jnp.sum(act, axis=(1, 2)) / nreal
-        unvisited = jnp.sum(jnp.logical_and(
-            vvals[spec.gather[0]] == ident,
-            vmask[None]).astype(jnp.float32), axis=(1, 2)) / nreal
-        deg = edges["t_deg"].astype(jnp.float32)
-        bnd = edges["t_bnd"].astype(jnp.float32)
-        # One direction serves every partition in this trace, so the vote
-        # threshold is the edge-mass-weighted blend of the per-partition
-        # fitted crossovers — exactly the shard's own fit when shard_map
-        # hands this trace a single partition.
-        emass = jnp.sum(deg, axis=1)
-        thr = (jnp.sum(edges["t_thr"][:, 0] * emass)
-               / jnp.maximum(jnp.sum(emass), 1.0))
-        want = _dopt_want(dopt_cfg.forced, density, unvisited, thr)
-        # Push examines every out-edge of a live vertex; the boundary leg
-        # always pushes (its messages ride the outbox/exchange either way),
-        # so pull is charged the boundary out-edges on top of its scans.
-        cnt_push = jnp.sum(act * deg[None], axis=(1, 2)).astype(jnp.int32)
-        cnt_bnd = jnp.sum(act * bnd[None], axis=(1, 2)).astype(jnp.int32)
-        zero = jnp.zeros((q,), jnp.int32)
+        with obs.phase("bsp.direction"):
+            # Per-vertex messages; the push direction's per-edge messages
+            # are gathers of exactly these values (the reference↔fused
+            # bitwise parity already leans on edge_fn ≡ gather∘edge_msg.fn).
+            vvals = {k: state[k].astype(jnp.float32) for k in spec.gather}
+            vconsts = {c: state[c][:, :, None].astype(jnp.float32)
+                       for c in spec.consts}
+            w_ident = None
+            if spec.use_weight:
+                w_ident = jnp.float32(0.0 if spec.weight_op == "add"
+                                      else 1.0)
+            xv = spec.fn(vvals, w_ident, step.astype(jnp.float32),
+                         vconsts).astype(jnp.float32)    # [Q, Pl, v_max]
+            vmask = edges["t_vmask"]
+            act = jnp.logical_and(xv != ident,
+                                  vmask[None]).astype(jnp.float32)
+            nreal = jnp.maximum(jnp.sum(vmask.astype(jnp.float32)), 1.0)
+            density = jnp.sum(act, axis=(1, 2)) / nreal
+            unvisited = jnp.sum(jnp.logical_and(
+                vvals[spec.gather[0]] == ident,
+                vmask[None]).astype(jnp.float32), axis=(1, 2)) / nreal
+            deg = edges["t_deg"].astype(jnp.float32)
+            bnd = edges["t_bnd"].astype(jnp.float32)
+            # One direction serves every partition in this trace, so the
+            # vote threshold is the edge-mass-weighted blend of the
+            # per-partition fitted crossovers — exactly the shard's own fit
+            # when shard_map hands this trace a single partition.
+            emass = jnp.sum(deg, axis=1)
+            thr = (jnp.sum(edges["t_thr"][:, 0] * emass)
+                   / jnp.maximum(jnp.sum(emass), 1.0))
+            want = _dopt_want(dopt_cfg.forced, density, unvisited, thr)
+            # Push examines every out-edge of a live vertex; the boundary
+            # leg always pushes (its messages ride the outbox/exchange
+            # either way), so pull is charged the boundary out-edges on top
+            # of its scans.
+            cnt_push = jnp.sum(act * deg[None],
+                               axis=(1, 2)).astype(jnp.int32)
+            cnt_bnd = jnp.sum(act * bnd[None],
+                              axis=(1, 2)).astype(jnp.int32)
+            zero = jnp.zeros((q,), jnp.int32)
 
         def run_push(opd):
             st, step = opd
@@ -786,18 +838,20 @@ def _superstep(dims: _Dims, program: VertexProgram, edges: dict,
             # the full compute's — the local region comes from the
             # bottom-up kernel instead.
             e_bnd = dict(edges)
-            e_bnd["dst_ext"] = jnp.where(edges["dst_ext"] < v_max, v_max,
-                                         edges["dst_ext"])
+            with obs.phase("bsp.reduce"):
+                e_bnd["dst_ext"] = jnp.where(edges["dst_ext"] < v_max,
+                                             v_max, edges["dst_ext"])
             acc_b = _compute_reference(dims, program, e_bnd, st, step)
-            offs = (jnp.arange(pl, dtype=jnp.int32)
-                    * (v_max + 1))[:, None, None]
-            colf = (edges["t_col"] + offs).reshape(pl * v_max, -1)
-            xf = jnp.concatenate(
-                [xv, jnp.full((q, pl, 1), ident, xv.dtype)],
-                axis=2).reshape(q, pl * (v_max + 1))
-            valf = None
-            if dopt_cfg.semiring == "min_plus":
-                valf = edges["t_val"].reshape(pl * v_max, -1)
+            with obs.phase("bsp.gather"):
+                offs = (jnp.arange(pl, dtype=jnp.int32)
+                        * (v_max + 1))[:, None, None]
+                colf = (edges["t_col"] + offs).reshape(pl * v_max, -1)
+                xf = jnp.concatenate(
+                    [xv, jnp.full((q, pl, 1), ident, xv.dtype)],
+                    axis=2).reshape(q, pl * (v_max + 1))
+                valf = None
+                if dopt_cfg.semiring == "min_plus":
+                    valf = edges["t_val"].reshape(pl * v_max, -1)
             # Uniform licence: already-written rows are final — a
             # sequential bottom-up visits only unvisited rows, so they
             # charge zero scanned slots in the work model.
@@ -809,13 +863,15 @@ def _superstep(dims: _Dims, program: VertexProgram, edges: dict,
                 colf, valf, xf, edges["t_kreal"].reshape(pl * v_max),
                 semiring=dopt_cfg.semiring, early_exit=dopt_cfg.uniform,
                 skip=skip, interpret=dopt_cfg.interpret)
-            acc = acc_b.at[:, :, :v_max].min(y.reshape(q, pl, v_max))
+            with obs.phase("bsp.reduce"):
+                acc = acc_b.at[:, :, :v_max].min(y.reshape(q, pl, v_max))
             cnt = jnp.sum(scanned, axis=1).astype(jnp.int32) + cnt_bnd
             return acc, zero, cnt
 
         acc, cp, cl = _direction_select(want, run_push, run_pull,
                                         (state, step))
-        dopt = _dopt_fold(dopt, want, cp + cl)
+        with obs.phase("bsp.direction"):
+            dopt = _dopt_fold(dopt, want, cp + cl)
     else:
         acc = compute_push(state, step)
 
@@ -828,31 +884,37 @@ def _superstep(dims: _Dims, program: VertexProgram, edges: dict,
         d_dims = _Dims(dims.num_parts, dims.v_max,
                        dyn["d_src"].shape[1], dims.o_max)
         d_acc = _compute_reference(d_dims, program, d_edges, state, step)
-        acc = _COMBINE[combine](acc, d_acc)
-    q = acc.shape[0]
-    local_acc = acc[:, :, : dims.v_max]
-    outbox = acc[:, :, dims.v_max + 1:].reshape(q, pl, dims.num_parts,
-                                                dims.o_max)
+        with obs.phase("bsp.reduce"):
+            acc = _COMBINE[combine](acc, d_acc)
 
-    # -- communicate: outbox -> symmetric inbox (paper Fig. 6); the wire
-    # ships Q slot blocks per pair — topology maps are never duplicated ----
-    inbox = exchange(outbox)  # [Q, pl, P, o_max]
+    with obs.phase("bsp.exchange"):
+        q = acc.shape[0]
+        local_acc = acc[:, :, : dims.v_max]
+        outbox = acc[:, :, dims.v_max + 1:].reshape(q, pl, dims.num_parts,
+                                                    dims.o_max)
 
-    # -- scatter: combine inbox messages into local vertex accumulator ------
-    offs = (jnp.arange(q * pl, dtype=jnp.int32)
-            * (dims.v_max + 1)).reshape(q, pl, 1, 1)
-    in_ids = edges["inbox_dst"][None] + offs
-    racc = seg_op(inbox.ravel(), in_ids.ravel(),
-                  num_segments=q * pl * (dims.v_max + 1))
-    racc = racc.reshape(q, pl, dims.v_max + 1)[:, :, : dims.v_max]
-    total = _COMBINE[combine](local_acc, racc)
+        # -- communicate: outbox -> symmetric inbox (paper Fig. 6); the
+        # wire ships Q slot blocks per pair — topology maps are never
+        # duplicated ---------------------------------------------------------
+        inbox = exchange(outbox)  # [Q, pl, P, o_max]
+
+        # -- scatter: combine inbox messages into local vertex accumulator -
+        offs = (jnp.arange(q * pl, dtype=jnp.int32)
+                * (dims.v_max + 1)).reshape(q, pl, 1, 1)
+        in_ids = edges["inbox_dst"][None] + offs
+        racc = seg_op(inbox.ravel(), in_ids.ravel(),
+                      num_segments=q * pl * (dims.v_max + 1))
+        racc = racc.reshape(q, pl, dims.v_max + 1)[:, :, : dims.v_max]
+        total = _COMBINE[combine](local_acc, racc)
 
     # -- apply + vote (per query) -------------------------------------------
-    new_state, finished = jax.vmap(program.apply_fn,
-                                   in_axes=(0, 0, None))(state, total, step)
-    if dopt is not None:
-        new_state = dict(new_state, **dopt)
-    return new_state, all_finished(finished)
+    with obs.phase("bsp.apply"):
+        new_state, finished = jax.vmap(program.apply_fn,
+                                       in_axes=(0, 0, None))(state, total,
+                                                             step)
+        if dopt is not None:
+            new_state = dict(new_state, **dopt)
+        return new_state, all_finished(finished)
 
 
 def _edges_dict(ea: EdgeArrays, blk: Optional[BlockMetadata] = None) -> dict:
@@ -892,9 +954,10 @@ def _run_batched_loop(step_fn: Callable, max_steps: int,
     def body(carry):
         st, step, fin, steps_q = carry
         new_st, vote = step_fn(st, step)
-        new_st = jax.tree.map(functools.partial(freeze, fin), new_st, st)
-        steps_q = steps_q + jnp.logical_not(fin).astype(jnp.int32)
-        return new_st, step + 1, jnp.logical_or(fin, vote), steps_q
+        with obs.phase("bsp.apply"):
+            new_st = jax.tree.map(functools.partial(freeze, fin), new_st, st)
+            steps_q = steps_q + jnp.logical_not(fin).astype(jnp.int32)
+            return new_st, step + 1, jnp.logical_or(fin, vote), steps_q
 
     def cond(carry):
         _, step, fin, _ = carry
@@ -927,9 +990,10 @@ def _run_chunked_loop(step_fn: Callable, chunk: int, max_steps: int,
     def body(carry):
         st, step, fin, steps_q = carry
         new_st, vote = step_fn(st, step)
-        new_st = jax.tree.map(functools.partial(freeze, fin), new_st, st)
-        steps_q = steps_q + jnp.logical_not(fin).astype(jnp.int32)
-        return new_st, step + 1, jnp.logical_or(fin, vote), steps_q
+        with obs.phase("bsp.apply"):
+            new_st = jax.tree.map(functools.partial(freeze, fin), new_st, st)
+            steps_q = steps_q + jnp.logical_not(fin).astype(jnp.int32)
+            return new_st, step + 1, jnp.logical_or(fin, vote), steps_q
 
     def cond(carry):
         _, step, fin, _ = carry
@@ -1047,10 +1111,11 @@ def _run_chunked_loop_guarded(step_fn: Callable, guard: _ExchangeGuard,
         st, step, fin, steps_q, bad = carry
         guard.reset()
         new_st, vote = step_fn(st, step)
-        new_st = jax.tree.map(functools.partial(freeze, fin), new_st, st)
-        steps_q = steps_q + jnp.logical_not(fin).astype(jnp.int32)
-        return (new_st, step + 1, jnp.logical_or(fin, vote), steps_q,
-                bad + guard.read())
+        with obs.phase("bsp.apply"):
+            new_st = jax.tree.map(functools.partial(freeze, fin), new_st, st)
+            steps_q = steps_q + jnp.logical_not(fin).astype(jnp.int32)
+            return (new_st, step + 1, jnp.logical_or(fin, vote), steps_q,
+                    bad + guard.read())
 
     def cond(carry):
         _, step, fin, _, _ = carry
@@ -1758,6 +1823,7 @@ class BSPEngine:
                 program.edge_msg.use_weight,
                 program.edge_msg.frontier_uniform)
 
+    @obs.span(obs.HYBRID_SPLIT)
     def _build_hybrid(self, program: VertexProgram, g,
                       with_push: bool) -> Tuple[_HybridCfg, dict, Any]:
         """One direction's degree split of ``g``: (static cfg, numpy array
@@ -1965,6 +2031,7 @@ class BSPEngine:
         without ``include_reverse`` partitioning)."""
         return None if self._uses_hybrid(program) else self.edges_for(program)
 
+    @obs.span(obs.EXECUTE)
     def execute(self, program: VertexProgram, state: BatchedState, *,
                 num_steps: Optional[int] = None,
                 chunk: Optional[int] = None,
@@ -2085,9 +2152,10 @@ class BSPEngine:
     def _dopt_finish(self, state: BatchedState) -> BatchedState:
         """Strip the direction carry and record per-query aggregates."""
         state = dict(state)
-        d = np.asarray(state.pop(_DOPT_KEYS[0]))
-        e = np.asarray(state.pop(_DOPT_KEYS[1]))
-        s = np.asarray(state.pop(_DOPT_KEYS[2]))
+        with obs.span(obs.WAIT):
+            d = np.asarray(state.pop(_DOPT_KEYS[0]))
+            e = np.asarray(state.pop(_DOPT_KEYS[1]))
+            s = np.asarray(state.pop(_DOPT_KEYS[2]))
         self.last_direction_stats = dict(
             direction=d,
             edges_examined=e.sum(axis=1).astype(np.int64),

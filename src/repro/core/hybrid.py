@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.graph import CSRGraph, from_edge_list
 from repro.core import perf_model
 from repro.core.partition import (EdgeArrays, PartitionedGraph,
@@ -198,7 +199,8 @@ def hybrid_spmv(dense: jax.Array, ell_col: jax.Array, ell_val: jax.Array,
     if squeeze:
         x = x[None]
     q = x.shape[0]
-    xs = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)], axis=1)
+    with obs.phase("bsp.gather"):
+        xs = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)], axis=1)
     y = kops.ell_spmv_op(ell_col, ell_val, xs, semiring=semiring,
                          interpret=interpret)
     if k_dense:
@@ -208,15 +210,17 @@ def hybrid_spmv(dense: jax.Array, ell_col: jax.Array, ell_val: jax.Array,
         # resident while_loop body and the out-of-core tiered jits (which
         # assemble y across jit boundaries) must round identically, so the
         # dense stage is compiled as the same isolated subgraph everywhere.
-        xd = jax.lax.optimization_barrier(x[:, :k_dense])
-        if semiring == PLUS_TIMES:
-            yh = jax.lax.optimization_barrier(
-                kops.dense_spmv_op(xd, dense, interpret=interpret))
-            y = y.at[:, :k_dense].add(yh)
-        else:
-            yh = jax.lax.optimization_barrier(
-                kops.dense_spmv_minplus_op(xd, dense, interpret=interpret))
-            y = y.at[:, :k_dense].min(yh)
+        with obs.phase("bsp.reduce"):
+            xd = jax.lax.optimization_barrier(x[:, :k_dense])
+            if semiring == PLUS_TIMES:
+                yh = jax.lax.optimization_barrier(
+                    kops.dense_spmv_op(xd, dense, interpret=interpret))
+                y = y.at[:, :k_dense].add(yh)
+            else:
+                yh = jax.lax.optimization_barrier(
+                    kops.dense_spmv_minplus_op(xd, dense,
+                                               interpret=interpret))
+                y = y.at[:, :k_dense].min(yh)
     return y[0] if squeeze else y
 
 
@@ -243,7 +247,8 @@ def hybrid_spmv_scan(dense: jax.Array, ell_col: jax.Array,
     if squeeze:
         x = x[None]
     q = x.shape[0]
-    xs = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)], axis=1)
+    with obs.phase("bsp.gather"):
+        xs = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)], axis=1)
     y, scanned = kops.bottomup_scan_op(
         ell_col, ell_val if semiring == MIN_PLUS else None, xs, kreal,
         semiring=semiring, early_exit=early_exit, skip=skip,
@@ -251,10 +256,11 @@ def hybrid_spmv_scan(dense: jax.Array, ell_col: jax.Array,
     if k_dense:
         # Same barrier discipline as hybrid_spmv — the two paths must round
         # identically so direction is purely a performance choice.
-        xd = jax.lax.optimization_barrier(x[:, :k_dense])
-        yh = jax.lax.optimization_barrier(
-            kops.dense_spmv_minplus_op(xd, dense, interpret=interpret))
-        y = y.at[:, :k_dense].min(yh)
+        with obs.phase("bsp.reduce"):
+            xd = jax.lax.optimization_barrier(x[:, :k_dense])
+            yh = jax.lax.optimization_barrier(
+                kops.dense_spmv_minplus_op(xd, dense, interpret=interpret))
+            y = y.at[:, :k_dense].min(yh)
     cnt = jnp.sum(scanned, axis=1)
     return (y[0], cnt[0]) if squeeze else (y, cnt)
 

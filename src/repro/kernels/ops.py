@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.graph import CSRGraph
 from repro.kernels import dense_spmv as _dense
 from repro.kernels import ell_spmv as _ell
@@ -183,11 +184,14 @@ def ell_spmv_op(col: jax.Array, val: jax.Array, x: jax.Array, *,
     squeeze = x.ndim == 1
     if squeeze:
         x = x[None]
-    g, val_t, bv, bk = _ell_gather(col, None if sr == "min" else val, x,
-                                   block_v, _ell.SEMIRINGS[sr][2])
-    _record("ell_spmv", interpret)
-    y = _ell.ell_spmv(g, val_t, semiring=sr, block_v=bv, block_k=bk,
-                      interpret=interpret)[:, :v]
+    with obs.phase(obs.ELL):
+        with obs.phase("bsp.gather"):
+            g, val_t, bv, bk = _ell_gather(col, None if sr == "min" else val,
+                                           x, block_v, _ell.SEMIRINGS[sr][2])
+        _record("ell_spmv", interpret)
+        with obs.phase("bsp.reduce"):
+            y = _ell.ell_spmv(g, val_t, semiring=sr, block_v=bv, block_k=bk,
+                              interpret=interpret)[:, :v]
     return y[0] if squeeze else y
 
 
@@ -223,16 +227,19 @@ def bottomup_scan_op(col: jax.Array, val: jax.Array | None, x: jax.Array,
         x = x[None]
         if skip is not None and skip.ndim == 1:
             skip = skip[None]
-    g, val_t, bv, bk = _ell_gather(col, val, x, block_v,
-                                   _ell.SEMIRINGS[semiring][2])
-    krealp = _pad_to(kreal.astype(jnp.int32), bv, 0)[None]
-    _record("bottomup_scan", interpret)
-    y, scanned = _bu.bottomup_scan(g, val_t, krealp, semiring=semiring,
-                                   early_exit=early_exit, block_v=bv,
-                                   block_k=bk, interpret=interpret)
-    y, scanned = y[:, :v], scanned[:, :v]
-    if skip is not None and early_exit:
-        scanned = jnp.where(skip, 0, scanned)
+    with obs.phase(obs.ELL):
+        with obs.phase("bsp.gather"):
+            g, val_t, bv, bk = _ell_gather(col, val, x, block_v,
+                                           _ell.SEMIRINGS[semiring][2])
+        _record("bottomup_scan", interpret)
+        with obs.phase("bsp.reduce"):
+            krealp = _pad_to(kreal.astype(jnp.int32), bv, 0)[None]
+            y, scanned = _bu.bottomup_scan(
+                g, val_t, krealp, semiring=semiring, early_exit=early_exit,
+                block_v=bv, block_k=bk, interpret=interpret)
+            y, scanned = y[:, :v], scanned[:, :v]
+        if skip is not None and early_exit:
+            scanned = jnp.where(skip, 0, scanned)
     if squeeze:
         return y[0], scanned[0]
     return y, scanned
@@ -400,8 +407,9 @@ def outbox_reduce_op(x: jax.Array, src: jax.Array, local: jax.Array,
         KERNEL_PATHS[("outbox_reduce", "xla")] += 1
         # Reference chain: each edge's flat slot id from its block's table.
         slot = jnp.take_along_axis(ids, local.reshape(nb, block_e), axis=1)
-        msgs = apply_weight(jnp.take(x, src, axis=1))       # [Q, e_pad]
-        msgs = jnp.where(mask > 0, msgs, ident)
+        with obs.phase("bsp.gather"):
+            msgs = apply_weight(jnp.take(x, src, axis=1))   # [Q, e_pad]
+            msgs = jnp.where(mask > 0, msgs, ident)
         acc = _merge_partials(msgs[:, None, :], slot.reshape(1, -1),
                               num_slots, combine)
         return acc[0] if squeeze else acc
@@ -484,14 +492,16 @@ def fused_superstep_op(msg_fn, vstate: jax.Array, weight, scal: jax.Array,
         KERNEL_PATHS[("fused_superstep", "xla")] += 1
         # Reference path expressed through the elementwise form.
         e_max = dst_ext.shape[1]
-        src_b = jnp.broadcast_to(src[None, :, :e_max], (q, pl_count, e_max))
-        vals = tuple(
-            jnp.take_along_axis(vstate[:, :, k_, :], src_b, axis=2)
-            for k_ in range(n_keys))
-        scals = tuple(scal[:, :, j:j + 1] for j in range(scal.shape[2]))
-        w = weight[:, :e_max] if weight is not None else None
-        msgs = msg_fn(vals, w, scals).astype(jnp.float32)
-        msgs = jnp.where(mask[:, :e_max] > 0, msgs, ident)
+        with obs.phase("bsp.gather"):
+            src_b = jnp.broadcast_to(src[None, :, :e_max],
+                                     (q, pl_count, e_max))
+            vals = tuple(
+                jnp.take_along_axis(vstate[:, :, k_, :], src_b, axis=2)
+                for k_ in range(n_keys))
+            scals = tuple(scal[:, :, j:j + 1] for j in range(scal.shape[2]))
+            w = weight[:, :e_max] if weight is not None else None
+            msgs = msg_fn(vals, w, scals).astype(jnp.float32)
+            msgs = jnp.where(mask[:, :e_max] > 0, msgs, ident)
         offs = (jnp.arange(q * pl_count, dtype=jnp.int32)
                 * num_segments).reshape(q, pl_count, 1)
         acc = seg_op(msgs.ravel(), (dst_ext[None] + offs).ravel(),

@@ -135,3 +135,34 @@ def test_dense_spmv_compiles(one_chip, op):
     fn = getattr(dense_spmv, op)
     _compile(lambda x, a: fn(x, a, block_n=256, block_k=256), one_chip,
              ((BFS_Q, DENSE_K), F32), ((DENSE_K, DENSE_K), F32))
+
+
+@pytest.mark.parametrize("alg", ["pagerank", "bfs"])
+def test_hybrid_loop_phases_on_the_chip(one_chip, alg):
+    """The hybrid cells' whole loop as the chip compiles it: every
+    gather, scatter, sort and kernel call carries a ``bsp.*`` phase, and the
+    ELL kernel runs inside the ``bsp.ell`` leg."""
+    from hlo_scopes import compiled_text, instructions, unphased
+    from repro.algorithms.bfs import multi_source_state
+    from repro.algorithms.pagerank import initial_state
+    from repro.core import graph as G, partition as PT
+    from repro.core.bsp import BSPEngine, batch_state
+
+    pg = PT.partition(G.uniform(12, 16, seed=3), 4, PT.HIGH)
+    engine = BSPEngine(pg, backend="hybrid", hybrid_k_dense=0,
+                       interpret=False)
+    if alg == "pagerank":
+        program, steps = make_pagerank_program(pg.num_vertices), 3
+        state, kernel = batch_state(initial_state(pg)), "ell_spmv"
+    else:
+        program, steps = BFS_PROGRAM, None
+        state = {"level": jnp.asarray(multi_source_state(pg, [0]))}
+        kernel = "bottomup_scan"
+    rows = instructions(compiled_text(
+        engine, program, state, steps, place=lambda tree: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)))
+    assert unphased(rows) == []
+    calls = [r for r in rows if r["target"] == "tpu_custom_call"]
+    assert calls and all(r["name"].startswith(kernel + ".") for r in calls)
+    assert all("/bsp.ell/bsp.reduce/" in r["op_name"] for r in calls)
