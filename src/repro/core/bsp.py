@@ -304,11 +304,13 @@ class _HybridCfg:
 
     The array payload travels separately (an ``arrs`` dict with keys
     ``dense``/``ell_col``/``ell_val``/``slot``/``hid`` and optionally
-    ``push_src``/``push_dst``/``push_w``): numpy in the static engine —
-    per-trace constants — but **traced jit arguments** in the dynamic
-    engine, so in-place edge mutations (core/dynamic.py) update the split
-    without retracing and compaction can never be served from a stale
-    compiled constant.
+    ``push_src``/``push_dst``/``push_w``/``ell_kreal``): numpy in the
+    static engine — per-trace constants, the ELL sliced with its chunk
+    widths in ``ell_width`` (``HybridGraph.sliced``) — but **traced jit
+    arguments** in the dynamic engine, whose ELL stays rectangular, so
+    in-place edge mutations (core/dynamic.py) update the split without
+    retracing and compaction can never be served from a stale compiled
+    constant.
     """
 
     semiring: str
@@ -370,10 +372,12 @@ def _superstep_hybrid(program: VertexProgram, cfg: _HybridCfg, arrs: dict,
         x = spec.fn(vals, w_ident, step.astype(jnp.float32),
                     consts).astype(jnp.float32)          # [Q, n]
 
+    widths = arrs.get("ell_width")      # sliced ELL (static engine)
+
     def pull(x):
         return hybrid_spmv(arrs["dense"], arrs["ell_col"], arrs["ell_val"],
                            x, semiring=cfg.semiring, k_dense=cfg.k_dense,
-                           interpret=cfg.interpret)
+                           widths=widths, interpret=cfg.interpret)
 
     if "push_src" in arrs:
         def push_msgs(x):
@@ -427,7 +431,7 @@ def _superstep_hybrid(program: VertexProgram, cfg: _HybridCfg, arrs: dict,
                     arrs["dense"], arrs["ell_col"], arrs["ell_val"], x,
                     arrs["ell_kreal"], semiring=cfg.semiring,
                     k_dense=cfg.k_dense, early_exit=cfg.uniform,
-                    skip=skip, interpret=cfg.interpret)
+                    skip=skip, widths=widths, interpret=cfg.interpret)
                 return y, jnp.zeros((q,), jnp.int32), scanned + e_dense
 
             y, cnt_push, cnt_pull = _direction_select(
@@ -1824,11 +1828,13 @@ class BSPEngine:
                 program.edge_msg.frontier_uniform)
 
     @obs.span(obs.HYBRID_SPLIT)
-    def _build_hybrid(self, program: VertexProgram, g,
-                      with_push: bool) -> Tuple[_HybridCfg, dict, Any]:
+    def _build_hybrid(self, program: VertexProgram, g, with_push: bool,
+                      sliced: bool = False) -> Tuple[_HybridCfg, dict, Any]:
         """One direction's degree split of ``g``: (static cfg, numpy array
         dict, the HybridGraph) — shared by the static cache and the dynamic
-        rebuild path."""
+        rebuild path.  ``sliced`` packs the ELL as ``HybridGraph.sliced``
+        (``ell_width`` holds the chunk widths) and counts its slots under
+        ``obs.ELL_PACK``; else the ELL stays rectangular."""
         from repro.core.graph import CSRGraph
         from repro.core.hybrid import degree_split
 
@@ -1851,6 +1857,11 @@ class BSPEngine:
 
         arrs = dict(dense=hg.dense_block, ell_col=hg.ell_col,
                     ell_val=hg.ell_val, slot=slot, hid=hid)
+        if sliced:
+            col, val, widths = hg.sliced()
+            arrs.update(ell_col=col, ell_val=val, ell_width=widths)
+            obs.count(obs.ELL_PACK, slots=col.size,
+                      nonzeros=hg.sparse_edges)
         if with_push and program.combine == MIN and self._direction_switch:
             arrs["push_src"] = hg.inv_perm[g.edge_sources()].astype(np.int32)
             arrs["push_dst"] = hg.inv_perm[g.col].astype(np.int32)
@@ -1886,7 +1897,7 @@ class BSPEngine:
         if key in self._hybrid_cache:
             return self._hybrid_cache[key]
         cfg, arrs, _ = self._build_hybrid(program, self.pg.source,
-                                          with_push=True)
+                                          with_push=True, sliced=True)
         self._hybrid_cache[key] = (cfg, arrs)
         return cfg, arrs
 

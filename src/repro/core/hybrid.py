@@ -61,7 +61,7 @@ class HybridGraph:
     num_vertices: int
     num_edges: int
     k_dense: int                 # |H| (0 → pure sparse)
-    perm: np.ndarray             # new id -> old id (degree-descending)
+    perm: np.ndarray             # new -> old id (H, then rows longest first)
     inv_perm: np.ndarray         # old id -> new id
     dense_block: np.ndarray      # [K, K] f32 (⊗ values; ⊕-identity non-edges)
     ell_col: np.ndarray          # [V, kmax] int32 (pull: in-neighbours)
@@ -91,6 +91,14 @@ class HybridGraph:
             self.dense_edges, self.dense_density, self.sparse_edges,
             boundary_slots=0, num_chips=num_chips)
 
+    def sliced(self):
+        """The ELL remainder in the sliced layout of kernels/ell_spmv.py:
+        ``(col [Σ w_c, C], val [Σ w_c, C], widths [nc])``.  The rows are
+        ordered longest first below the dense block, so each chunk's width
+        is close to its rows' real lengths."""
+        return kops.sliced_ell(self.ell_col, self.ell_val, self.num_vertices,
+                               SEMIRINGS[self.semiring][2])
+
 
 def _degree_perm(g: CSRGraph):
     """Degree-descending vertex ranking (new -> old) and its inverse."""
@@ -116,6 +124,12 @@ def degree_split(g: CSRGraph, k_dense: int,
                  semiring: str = PLUS_TIMES) -> HybridGraph:
     """Split ``g``: top-``k_dense`` degree vertices → dense block.
 
+    The top ``k_dense`` vertices keep the total-degree order of
+    ``edge_max_ranks`` (the planner's and the dense block's); the others
+    follow by ELL row length (in-degree), longest first, ties in
+    total-degree order, so the sliced ELL's chunks hold rows of near-equal
+    length (``HybridGraph.sliced``).
+
     Edge ⊗ values follow the semiring (kernels/ops.csr_to_ell): weights where
     the graph has them, multiplicity counts (``plus_times``) or zero-cost
     hops (``min_plus``) otherwise.  Multi-edges accumulate with ⊕ in the
@@ -123,7 +137,12 @@ def degree_split(g: CSRGraph, k_dense: int,
     """
     if semiring not in SEMIRINGS:
         raise ValueError(f"unknown semiring {semiring!r}")
-    perm, inv = _degree_perm(g)
+    perm, _ = _degree_perm(g)
+    tail = perm[k_dense:]
+    tail = tail[np.argsort(-g.in_degrees()[tail], kind="stable")]
+    perm = np.concatenate([perm[:k_dense], tail])
+    inv = np.empty_like(perm)
+    inv[perm] = np.arange(len(perm))
     src = inv[g.edge_sources()]
     dst = inv[g.col]
     if semiring == PLUS_TIMES:
@@ -183,6 +202,7 @@ def auto_degree_split(g: CSRGraph, semiring: str = PLUS_TIMES,
 
 def hybrid_spmv(dense: jax.Array, ell_col: jax.Array, ell_val: jax.Array,
                 x: jax.Array, *, semiring: str, k_dense: int,
+                widths: Optional[np.ndarray] = None,
                 interpret: Optional[bool] = None) -> jax.Array:
     """One generalized two-engine step: y[v] = ⊕ over in-edges x[u] ⊗ w.
 
@@ -192,7 +212,9 @@ def hybrid_spmv(dense: jax.Array, ell_col: jax.Array, ell_val: jax.Array,
     ``[Q, n]`` *query batch* of such vectors, in which case the batch rides
     the MXU's M axis (SpMV becomes SpMM: Q concurrent traversals amortize
     one pass over the resident adjacency) and the ELL kernel's leading grid
-    axis; returns ``[Q, n]``.
+    axis; returns ``[Q, n]``.  ``ell_col``/``ell_val`` are rectangular
+    ``[n, kmax]``, or sliced with their chunk ``widths``
+    (``HybridGraph.sliced``).
     """
     ident = add_identity(semiring)
     squeeze = x.ndim == 1
@@ -202,7 +224,7 @@ def hybrid_spmv(dense: jax.Array, ell_col: jax.Array, ell_val: jax.Array,
     with obs.phase("bsp.gather"):
         xs = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)], axis=1)
     y = kops.ell_spmv_op(ell_col, ell_val, xs, semiring=semiring,
-                         interpret=interpret)
+                         widths=widths, interpret=interpret)
     if k_dense:
         # The barriers pin the dense stage's rounding: interpret-mode
         # Pallas inlines the dot, and XLA's FMA-contraction choice for the
@@ -229,6 +251,7 @@ def hybrid_spmv_scan(dense: jax.Array, ell_col: jax.Array,
                      kreal: jax.Array, *, semiring: str, k_dense: int,
                      early_exit: bool = False,
                      skip: Optional[jax.Array] = None,
+                     widths: Optional[np.ndarray] = None,
                      interpret: Optional[bool] = None):
     """``hybrid_spmv`` with the bottom-up scan kernel on the ELL path.
 
@@ -251,7 +274,7 @@ def hybrid_spmv_scan(dense: jax.Array, ell_col: jax.Array,
         xs = jnp.concatenate([x, jnp.full((q, 1), ident, x.dtype)], axis=1)
     y, scanned = kops.bottomup_scan_op(
         ell_col, ell_val if semiring == MIN_PLUS else None, xs, kreal,
-        semiring=semiring, early_exit=early_exit, skip=skip,
+        semiring=semiring, early_exit=early_exit, skip=skip, widths=widths,
         interpret=interpret)
     if k_dense:
         # Same barrier discipline as hybrid_spmv — the two paths must round
@@ -625,8 +648,8 @@ def hybrid_pagerank(hg: HybridGraph, num_iterations: int = 20,
         raise ValueError("hybrid_pagerank needs a plus_times split")
     n = hg.num_vertices
     dense = jnp.asarray(hg.dense_block)
-    col = jnp.asarray(hg.ell_col)
-    val = jnp.asarray(hg.ell_val)
+    col, val, widths = hg.sliced()
+    col, val = jnp.asarray(col), jnp.asarray(val)
     inv_deg = jnp.asarray(np.where(hg.out_deg > 0,
                                    1.0 / np.maximum(hg.out_deg, 1.0), 0.0))
     delta = (1.0 - damping) / n
@@ -635,7 +658,8 @@ def hybrid_pagerank(hg: HybridGraph, num_iterations: int = 20,
     def step(rank):
         contrib = rank * inv_deg
         y = hybrid_spmv(dense, col, val, contrib, semiring=PLUS_TIMES,
-                        k_dense=hg.k_dense, interpret=interpret)
+                        k_dense=hg.k_dense, widths=widths,
+                        interpret=interpret)
         return delta + damping * y
 
     rank = jnp.full((n,), 1.0 / n, dtype=jnp.float32)

@@ -36,7 +36,9 @@ on.
 ``kreal[v]`` is the row's real (non-sentinel) slot count; sentinel slots
 gather the +inf sink and can never register a hit, so rows report at most
 their real work.  The gathered values carry the query-batch axis and the
-slot-major ``[Q, K, V]`` layout of ell_spmv, and the grid is the same.
+sliced ``[Q, Σ w_c, C]`` layout of ell_spmv, and the grid and the row fold
+are the same; a row's first hit is the same slot in any chunk width, so
+``scanned`` is too.
 """
 from __future__ import annotations
 
@@ -45,81 +47,87 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ell_spmv import accumulate, row_reduce
+from repro.kernels.ell_spmv import SLOTS, accumulate, row_reduce, step_specs
+
+# A row with no hit keeps this first-hit slot; min(· + 1, kreal) turns it
+# into the row's kreal.
+_NO_HIT = 1 << 30
 
 
-def _bu_kernel(g_ref, *rest, semiring: str, early_exit: bool,
-               block_k: int, k_total: int):
+def _bu_kernel(chunk_ref, blk_ref, g_ref, *rest, semiring: str,
+               early_exit: bool):
     if semiring == "min_plus":
         v_ref, kreal_ref, o_ref, s_ref = rest
         vals = v_ref[...]
     else:
         kreal_ref, o_ref, s_ref = rest
         vals = None
-    g = g_ref[...]                                   # [Q, bk, bv]
-    accumulate(o_ref, row_reduce(g, vals, semiring), "min")
-    k = pl.program_id(1)
+    t = pl.program_id(0)
+    blk = blk_ref[t]
+    first = blk == 0
+    g = g_ref[...]                                   # [Q, 8, C]
+    accumulate(o_ref, row_reduce(g, vals, semiring), "min", first)
     kreal = jnp.broadcast_to(kreal_ref[...], s_ref.shape)
     if not early_exit:
         s_ref[...] = kreal
         return
     # A "hit" is a live *parent* (x finite), judged before the ⊗ add — the
-    # scan stops on reaching any frontier in-neighbour.  Rows with no hit
-    # keep k_total, which min(· + 1, kreal) turns into their kreal.
-    idx = k * block_k + jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
-    first = jnp.min(jnp.where(g < jnp.inf, idx, k_total), axis=1)
+    # scan stops on reaching any frontier in-neighbour.
+    idx = blk * SLOTS + jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    hit = jnp.min(jnp.where(g < jnp.inf, idx, _NO_HIT), axis=1)
 
-    @pl.when(k == 0)
+    @pl.when(first)
     def _init():
-        s_ref[...] = first
+        s_ref[...] = hit
 
-    @pl.when(k > 0)
+    @pl.when(jnp.logical_not(first))
     def _fold():
-        s_ref[...] = jnp.minimum(s_ref[...], first)
+        s_ref[...] = jnp.minimum(s_ref[...], hit)
 
-    @pl.when(k == pl.num_programs(1) - 1)
+    # The chunk's last block: the next step starts another chunk (or none).
+    nxt = jnp.minimum(t + 1, pl.num_programs(0) - 1)
+    last = jnp.logical_or(t == pl.num_programs(0) - 1, blk_ref[nxt] == 0)
+
+    @pl.when(last)
     def _finish():
         s_ref[...] = jnp.minimum(s_ref[...] + 1, kreal)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("semiring", "early_exit", "block_v",
-                                    "block_k", "interpret"))
-def bottomup_scan(g: jax.Array, val_t: jax.Array | None, kreal: jax.Array,
-                  *, semiring: str, early_exit: bool = False,
-                  block_v: int, block_k: int, interpret: bool = False):
-    """Bottom-up scan over a (row-block, slot-block) grid.
+                   static_argnames=("semiring", "early_exit", "chunks",
+                                    "interpret"))
+def bottomup_scan(chunk_of: jax.Array, blk_of: jax.Array, g: jax.Array,
+                  val: jax.Array | None, kreal: jax.Array, *, semiring: str,
+                  early_exit: bool = False, chunks: int,
+                  interpret: bool = False):
+    """Bottom-up scan over the sliced grid of ``ell_spmv``.
 
-    g: [Q, K, V] f32 gathered in-neighbour values (``x[col.T]``, the
-    ⊕-identity sink at sentinel slots); val_t: [K, V] f32 (``min_plus``)
-    or None (``min``); kreal: [1, V] int32 real slot counts.  Returns
-    ``(y [Q, V] f32, scanned [Q, V] int32)``.  V must be a multiple of
-    block_v and K of block_k (ops.py pads).
+    chunk_of, blk_of: [S/8] int32 step tables (``ops.step_tables``); g:
+    [Q, S, C] f32 gathered in-neighbour values (the ⊕-identity sink at
+    sentinel slots); val: [S, C] f32 (``min_plus``) or None (``min``);
+    kreal: [1, chunks·C] int32 real slot counts.  Returns
+    ``(y [Q, chunks·C] f32, scanned [Q, chunks·C] int32)``.
     """
     if semiring not in ("min", "min_plus"):
         raise ValueError(f"bottom-up scan needs a min combine, "
                          f"got {semiring!r}")
-    q, k, v = g.shape
-    assert v % block_v == 0 and k % block_k == 0, "ops.bottomup_scan_op pads"
-    assert kreal.shape == (1, v)
-    in_specs = [pl.BlockSpec((q, block_k, block_v), lambda i, j: (0, j, i))]
-    args = [g]
-    if semiring == "min_plus":
-        in_specs.append(pl.BlockSpec((block_k, block_v),
-                                     lambda i, j: (j, i)))
-        args.append(val_t)
-    in_specs.append(pl.BlockSpec((1, block_v), lambda i, j: (0, i)))
-    out_spec = pl.BlockSpec((q, block_v), lambda i, j: (0, i))
+    q, s, c = g.shape
+    assert s % SLOTS == 0 and chunk_of.shape == (s // SLOTS,)
+    assert kreal.shape == (1, chunks * c)
+    in_specs, out_spec = step_specs(q, c, semiring == "min_plus")
+    in_specs.append(pl.BlockSpec((1, c), lambda t, ch, bk: (0, ch[t])))
+    args = [g] if semiring == "min" else [g, val]
     kernel = functools.partial(_bu_kernel, semiring=semiring,
-                               early_exit=early_exit, block_k=block_k,
-                               k_total=k)
+                               early_exit=early_exit)
+    shape = (q, chunks * c)
     return pl.pallas_call(
         kernel,
-        grid=(v // block_v, k // block_k),
-        in_specs=in_specs,
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((q, v), jnp.float32),
-                   jax.ShapeDtypeStruct((q, v), jnp.int32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(s // SLOTS,), in_specs=in_specs,
+            out_specs=[out_spec, out_spec]),
+        out_shape=[jax.ShapeDtypeStruct(shape, jnp.float32),
+                   jax.ShapeDtypeStruct(shape, jnp.int32)],
         interpret=interpret,
-    )(*args, kreal)
+    )(chunk_of, blk_of, *args, kreal)

@@ -1,15 +1,36 @@
-"""ELLPACK SpMV Pallas kernel — the sparse/VPU path of the hybrid engine.
+"""Sliced-ELL SpMV Pallas kernel — the sparse/VPU path of the hybrid engine.
 
-The low-degree remainder of a degree-partitioned scale-free graph has a tight
-degree bound, so ELLPACK padding is cheap: ``col[V, K]`` holds up to K
-neighbour ids per vertex (sentinel-padded), ``val[V, K]`` the edge values.
+The low-degree remainder of a degree-partitioned graph has a tight degree
+bound, so ELLPACK is the natural layout: up to K neighbour ids per row,
+sentinel-padded.  Padding every row to the widest one wastes slots, so the
+rows are stored **sliced** (SELL-C-σ, Kreutzer et al., SIAM J. Sci. Comput.
+36(5), 2014): rows are cut into chunks of ``C`` (``CHUNK``, 512, the
+kernel's lane block) and chunk ``c`` is padded only to its own width
+``w_c``, its longest row rounded up to ``SLOTS`` (8).  Chunk after chunk, the chunks' slot rows make
+one ``col[Σ w_c, C]`` (and ``val``) array: chunk ``c`` owns slot rows
+``off_c .. off_c + w_c`` and lane ``r`` of them is its row ``c·C + r``.
+When the rows are ordered longest first (``core/hybrid.degree_split``), the
+chunks' widths follow the degree distribution and the padding nearly
+vanishes.  A rectangular ``[V, K]`` ELL is the sliced one with every chunk
+at width ``ceil8(K)`` (``ops.rect_as_sliced``).
+
 The per-slot source values ``x[col]`` are gathered by XLA ahead of the
 kernel (an arbitrary-index gather does not lower inside Mosaic) into a
-slot-major ``[Q, K, V]`` array; the kernel streams its ``(Q, bk, bv)``
-blocks HBM→VMEM (grid pipelining double-buffers the DMA — the
-latency-hiding role the GPU's hardware multithreading plays in the paper)
-and reduces them across the slot axis into lane-dense ``(Q, bv)`` rows.
-The gather moves ``Q × V × K`` values, i.e. the padded edge count.
+``[Q, Σ w_c, C]`` array; the gather's cost follows its index count, the
+sliced slot count.  The kernel's 1-D grid walks the ``Σ w_c / 8`` blocks of
+8 slot rows in storage order; two int32 step tables, read by scalar
+prefetch, give each step its chunk (the output block the index map picks)
+and its block index within the chunk (0 starts the row fold).  Consecutive
+steps of one chunk keep the output block resident in VMEM, the
+grouped-matmul pattern, so every step does work and none is skipped.
+
+**One reduction order for every layout.** A row's slots are folded in
+8-slot blocks, in slot order: each block is reduced on its own
+(``row_reduce``), then folded into the row's value (``accumulate``).  A
+row's extra blocks in a wider chunk hold only sentinel slots, whose
+reduction is the exact ⊕-identity (``+0.0``, or ``+inf`` under ``min``),
+so the same row gives bitwise the same ``y`` in any chunk width: sliced,
+rectangular, or a window of a rectangular ELL.
 
 Three semirings cover the TOTEM algorithms (paper §3.4 reduction classes):
   - ``plus_times``: y[v] = Σ_k x[col[v,k]] · val[v,k]      (PageRank, BC)
@@ -25,9 +46,8 @@ contributes.  ``combine="sum"|"min"`` remains as a back-compat alias for
 
 The value vector carries a leading **query-batch axis**: ``x[Q, x_len]`` →
 ``y[Q, V]``.  The topology (``col``/``val``) is shared across the batch:
-each grid step ``(V/bv, K/bk)`` reduces every query's block against one
-``(bk, bv)`` tile of ``val``, and the slot axis is innermost so the output
-tile accumulates in VMEM.
+each grid step reduces every query's ``(Q, 8, C)`` block against one
+``(8, C)`` tile of ``val``.
 """
 from __future__ import annotations
 
@@ -36,6 +56,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # semiring → (⊕ name, ⊕ identity, ⊗ identity for sentinel slots)
 SEMIRINGS = {
@@ -44,8 +65,11 @@ SEMIRINGS = {
     "min": ("min", float("inf"), 0.0),
 }
 _COMBINE_ALIAS = {"sum": "plus_times", "min": "min_plus"}
-# VMEM bytes for one buffer of the [Q, bk, bv] gathered block.
-BLOCK_BYTES = 4 << 20
+# Slot rows per grid step: the unit of a row's fold, and of chunk widths.
+SLOTS = 8
+# Rows per chunk: the kernel's lane block (an ELL of fewer rows takes the
+# fewest lanes, a multiple of 128, that hold them).
+CHUNK = 512
 
 
 def resolve_semiring(combine: str | None, semiring: str | None) -> str:
@@ -57,15 +81,6 @@ def resolve_semiring(combine: str | None, semiring: str | None) -> str:
     return _COMBINE_ALIAS[combine or "sum"]
 
 
-def slot_block(q: int, k: int, block_v: int) -> int:
-    """Slots per grid step: all ``k`` when a ``[q, k, block_v]`` f32 block
-    fits ``BLOCK_BYTES``, else the largest multiple of 8 that does.
-    Depends on the batch and row width only, never on V, so every row
-    reduces in the same order whatever the call's row count."""
-    fit = BLOCK_BYTES // (4 * q * block_v)
-    return k if k <= fit else max(8, fit // 8 * 8)
-
-
 def row_reduce(g, vals, semiring: str):
     """⊕ over the slot axis 1 of ``g [Q, bk, bv]`` ⊗ ``vals [bk, bv]``."""
     if semiring == "plus_times":
@@ -75,15 +90,15 @@ def row_reduce(g, vals, semiring: str):
     return jnp.min(g, axis=1)
 
 
-def accumulate(o_ref, part, semiring: str) -> None:
-    """Fold one slot block's reduction into the resident output tile."""
-    k = pl.program_id(1)
+def accumulate(o_ref, part, semiring: str, first) -> None:
+    """Fold one slot block's reduction into the resident output tile;
+    ``first`` (a traced bool) starts the row fold."""
 
-    @pl.when(k == 0)
+    @pl.when(first)
     def _init():
         o_ref[...] = part
 
-    @pl.when(k > 0)
+    @pl.when(jnp.logical_not(first))
     def _fold():
         if semiring == "plus_times":
             o_ref[...] = o_ref[...] + part
@@ -91,42 +106,52 @@ def accumulate(o_ref, part, semiring: str) -> None:
             o_ref[...] = jnp.minimum(o_ref[...], part)
 
 
-def _ell_kernel(g_ref, *rest, semiring: str):
+def step_specs(q: int, chunk: int, with_val: bool):
+    """Block specs of the sliced grid: the step's ``(Q, 8, C)`` gathered
+    block, its ``(8, C)`` values (``with_val``) and the ``(Q, C)`` output
+    tile of the step's chunk.  Index maps take the step and the two
+    scalar-prefetched tables (chunk of each step, block within it)."""
+    g = pl.BlockSpec((q, SLOTS, chunk), lambda t, ch, bk: (0, t, 0))
+    val = pl.BlockSpec((SLOTS, chunk), lambda t, ch, bk: (t, 0))
+    out = pl.BlockSpec((q, chunk), lambda t, ch, bk: (0, ch[t]))
+    return [g, val] if with_val else [g], out
+
+
+def _ell_kernel(chunk_ref, blk_ref, g_ref, *rest, semiring: str):
     if semiring == "min":
         (o_ref,) = rest
         vals = None
     else:
         v_ref, o_ref = rest
         vals = v_ref[...]
-    accumulate(o_ref, row_reduce(g_ref[...], vals, semiring), semiring)
+    first = blk_ref[pl.program_id(0)] == 0
+    accumulate(o_ref, row_reduce(g_ref[...], vals, semiring), semiring,
+               first)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("semiring", "block_v", "block_k",
-                                    "interpret"))
-def ell_spmv(g: jax.Array, val_t: jax.Array | None, *, semiring: str,
-             block_v: int, block_k: int, interpret: bool = False
-             ) -> jax.Array:
-    """Row reduction of gathered ELL slots over a (row-block, slot-block)
-    grid.
+                   static_argnames=("semiring", "chunks", "interpret"))
+def ell_spmv(chunk_of: jax.Array, blk_of: jax.Array, g: jax.Array,
+             val: jax.Array | None, *, semiring: str, chunks: int,
+             interpret: bool = False) -> jax.Array:
+    """Row reduction of a sliced ELL's gathered slots, one 8-slot block per
+    grid step.
 
-    g: [Q, K, V] f32 source values per slot (``x[col.T]``); val_t: [K, V]
-    edge values (None for ``min``).  Returns y: [Q, V] f32.  V must be a
-    multiple of block_v and K of block_k (ops.py pads).
+    chunk_of, blk_of: [S/8] int32 per step: its chunk, and its block index
+    within the chunk (``ops.step_tables``).  g: [Q, S, C] f32 source
+    values per slot (``x[col]``); val: [S, C] edge values (None for
+    ``min``).  Returns y: [Q, chunks·C] f32, chunk ``c`` at lanes
+    ``c·C .. c·C + C``.
     """
-    q, k, v = g.shape
-    assert v % block_v == 0 and k % block_k == 0, "ops.ell_spmv_op pads"
-    in_specs = [pl.BlockSpec((q, block_k, block_v), lambda i, j: (0, j, i))]
-    args = [g]
-    if semiring != "min":
-        in_specs.append(pl.BlockSpec((block_k, block_v),
-                                     lambda i, j: (j, i)))
-        args.append(val_t)
+    q, s, c = g.shape
+    assert s % SLOTS == 0 and chunk_of.shape == (s // SLOTS,)
+    in_specs, out_spec = step_specs(q, c, semiring != "min")
+    args = [g] if semiring == "min" else [g, val]
     return pl.pallas_call(
         functools.partial(_ell_kernel, semiring=semiring),
-        grid=(v // block_v, k // block_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((q, block_v), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((q, v), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(s // SLOTS,), in_specs=in_specs,
+            out_specs=out_spec),
+        out_shape=jax.ShapeDtypeStruct((q, chunks * c), jnp.float32),
         interpret=interpret,
-    )(*args)
+    )(chunk_of, blk_of, *args)

@@ -146,51 +146,110 @@ def csr_to_ell(g: CSRGraph, combine: str | None = None,
     return col, val, kmax
 
 
-def _ell_gather(col: jax.Array, val: jax.Array | None, x: jax.Array,
-                block_v: int, mul_ident: float):
-    """Pad the ELL rows and slots to the kernel's blocks and gather the
-    slot-major ``[Q, K, V]`` source values ``x[col.T]`` in XLA.
+def _chunks(a, fill, xp=np):
+    """``[V, K]`` rows as ``[nc, kp, C]``: chunks of ``C`` rows (``CHUNK``,
+    or fewer lanes, a multiple of 128, for a small ELL), slots padded to
+    ``kp = ceil8(K)`` (at least 8), padding filled with ``fill``."""
+    slots = _ell.SLOTS
+    v, k = a.shape
+    c = min(_ell.CHUNK, max(128, -(-v // 128) * 128))
+    nc = -(-v // c)
+    kp = max(slots, -(-k // slots) * slots)
+    a = xp.pad(a, ((0, nc * c - v), (0, kp - k)), constant_values=fill)
+    return a.reshape(nc, c, kp).transpose(0, 2, 1)
 
-    Padding rows and slots point at the sentinel (x's last column, the
-    ⊕-identity sink) with ⊗-identity values.  Returns
-    ``(g, val_t, bv, bk)``."""
-    v, k = col.shape
-    sentinel = x.shape[1] - 1           # callers append the ⊕-identity slot
-    bv = min(block_v, max(128, -(-v // 128) * 128))
-    bk = _ell.slot_block(x.shape[0], k, block_v)
-    col_t = _pad_to(_pad_to(col.T, bk, 0, value=sentinel), bv, 1,
-                    value=sentinel)
-    g = jnp.take(x, col_t, axis=1, mode="clip")          # [Q, Kp, Vp]
-    val_t = None
+
+def sliced_ell(col: np.ndarray, val: np.ndarray | None, sentinel: int,
+               mul_ident: float):
+    """Pack a rectangular ``[V, K]`` ELL, rows in their final order and
+    each row's real (non-``sentinel``) slots first, as ``csr_to_ell``
+    packs them, into the sliced layout of ``kernels/ell_spmv.py`` (numpy
+    preprocessing).
+
+    Chunk ``c`` of ``C`` rows gets the width ``w_c``: its longest row,
+    rounded up to 8, and at least 8.  Returns ``(col [Σ w_c, C] int32,
+    val [Σ w_c, C] f32 or None, widths [nc] int32)``; padding rows and
+    slots are sentinels with ⊗-identity values."""
+    slots = _ell.SLOTS
+    t = _chunks(col, sentinel)                                  # [nc, kp, C]
+    nc, kp, c = t.shape
+    length = np.pad((col != sentinel).sum(axis=1), (0, nc * c - len(col)))
+    longest = length.reshape(nc, c).max(axis=1)
+    widths = np.maximum(slots, -(-longest // slots) * slots).astype(np.int32)
+    keep = np.arange(kp)[None, :] < widths[:, None]             # [nc, kp]
+    col = np.ascontiguousarray(t[keep])                         # [Σw, C]
     if val is not None:
-        val_t = _pad_to(_pad_to(val.T, bk, 0, value=mul_ident), bv, 1,
-                        value=mul_ident)
-    return g, val_t, bv, bk
+        val = np.ascontiguousarray(_chunks(val, mul_ident)[keep])
+    return col, val, widths
+
+
+def rect_as_sliced(col: jax.Array, val: jax.Array | None, sentinel,
+                   mul_ident: float):
+    """A rectangular ``[V, K]`` ELL (numpy or traced) as the sliced layout
+    with every chunk at the full width ``ceil8(K)``: the same rows, folded
+    in the same order, so ``y`` is bitwise what a packed layout of the same
+    rows gives.  Returns ``(col, val, widths)`` as ``sliced_ell``."""
+
+    def pack(a, fill):
+        t = _chunks(a, fill, jnp)
+        return t.reshape(-1, t.shape[2]), t.shape
+
+    col, (nc, kp, _) = pack(col, sentinel)
+    if val is not None:
+        val, _ = pack(val, mul_ident)
+    return col, val, np.full(nc, kp, np.int32)
+
+
+def step_tables(widths: np.ndarray):
+    """The sliced kernels' per-step tables: each 8-slot block's chunk and
+    its block index within the chunk, in storage order (int32)."""
+    nb = np.asarray(widths, np.int64) // _ell.SLOTS
+    chunk_of = np.repeat(np.arange(len(nb)), nb)
+    blk_of = np.arange(int(nb.sum())) - np.repeat(np.cumsum(nb) - nb, nb)
+    return (jnp.asarray(chunk_of, jnp.int32),
+            jnp.asarray(blk_of, jnp.int32))
+
+
+def _sliced(col, val, x, widths, mul_ident: float):
+    """Gather the ELL's source values ``x[col]`` in XLA, in the sliced
+    layout: a rectangular ``col [V, K]`` (``widths`` None) is sliced at
+    full width first.  Sentinel slots point at x's last column, the
+    ⊕-identity sink.  Returns ``(g [Q, S, C], val, widths)``."""
+    if widths is None:
+        col, val, widths = rect_as_sliced(col, val, x.shape[1] - 1,
+                                          mul_ident)
+    g = jnp.take(x, col, axis=1, mode="clip")            # [Q, S, C]
+    return g, val, widths
 
 
 def ell_spmv_op(col: jax.Array, val: jax.Array, x: jax.Array, *,
                 combine: str | None = None, semiring: str | None = None,
-                block_v: int = 512,
+                widths: np.ndarray | None = None,
                 interpret: bool | None = None) -> jax.Array:
-    """ELL SpMV for arbitrary V and K; pads rows and slots to the blocks.
+    """ELL SpMV through the sliced kernel.
 
-    ``x`` may be ``[x_len]`` (one query, returns ``[V]``) or ``[Q, x_len]``
-    (query batch, returns ``[Q, V]``); the topology is shared across Q.
+    ``col``/``val`` are a rectangular ``[V, K]`` ELL (``widths`` None) or
+    a sliced one (``[Σ w_c, C]`` with its per-chunk ``widths``, from
+    ``sliced_ell``), whose V rows are x's: its sentinel, x's last column,
+    is V.  ``x`` may be ``[x_len]`` (one query, returns ``[V]``) or
+    ``[Q, x_len]`` (query batch, returns ``[Q, V]``); the topology is
+    shared across Q.
     """
     if interpret is None:
         interpret = _interpret_default()
     sr = _ell.resolve_semiring(combine, semiring)
-    v = col.shape[0]
     squeeze = x.ndim == 1
     if squeeze:
         x = x[None]
+    v = col.shape[0] if widths is None else x.shape[1] - 1
     with obs.phase(obs.ELL):
         with obs.phase("bsp.gather"):
-            g, val_t, bv, bk = _ell_gather(col, None if sr == "min" else val,
-                                           x, block_v, _ell.SEMIRINGS[sr][2])
+            g, val, widths = _sliced(col, None if sr == "min" else val, x,
+                                     widths, _ell.SEMIRINGS[sr][2])
         _record("ell_spmv", interpret)
         with obs.phase("bsp.reduce"):
-            y = _ell.ell_spmv(g, val_t, semiring=sr, block_v=bv, block_k=bk,
+            y = _ell.ell_spmv(*step_tables(widths), g, val, semiring=sr,
+                              chunks=len(widths),
                               interpret=interpret)[:, :v]
     return y[0] if squeeze else y
 
@@ -198,16 +257,17 @@ def ell_spmv_op(col: jax.Array, val: jax.Array, x: jax.Array, *,
 def bottomup_scan_op(col: jax.Array, val: jax.Array | None, x: jax.Array,
                      kreal: jax.Array, *, semiring: str,
                      early_exit: bool = False, skip: jax.Array | None = None,
-                     block_v: int = 512,
+                     widths: np.ndarray | None = None,
                      interpret: bool | None = None):
-    """Bottom-up pull scan for arbitrary V; pads rows to the block size.
+    """Bottom-up pull scan through the sliced kernel.
 
-    ``col`` [V, K] in-neighbour ids (sentinel = x_len-1), ``val`` [V, K]
-    (``min_plus``) or None (``min``), ``x`` [Q, x_len] with the ⊕-identity
-    sink appended per row, ``kreal`` [V] real slot counts.  Returns
-    ``(y [Q, V], scanned [Q, V] int32)`` — the row reduction (bitwise equal
-    to ``ell_spmv_op``'s) plus the early-exit scan-work model
-    (kernels/bottomup.py).  Padding rows report zero scanned slots.
+    ``col`` [V, K] in-neighbour ids (sentinel = x_len-1), or a sliced ELL
+    with its ``widths`` as in ``ell_spmv_op``; ``val`` of the same layout
+    (``min_plus``) or None (``min``); ``x`` [Q, x_len] with the
+    ⊕-identity sink appended per row; ``kreal`` [V] real slot counts.
+    Returns ``(y [Q, V], scanned [Q, V] int32)`` — the row reduction
+    (bitwise equal to ``ell_spmv_op``'s) plus the early-exit scan-work
+    model (kernels/bottomup.py).
 
     ``skip`` [Q, V] bool (uniform-frontier programs only, alongside
     ``early_exit``) marks rows whose value is already final — under
@@ -221,7 +281,7 @@ def bottomup_scan_op(col: jax.Array, val: jax.Array | None, x: jax.Array,
 
     if interpret is None:
         interpret = _interpret_default()
-    v = col.shape[0]
+    v = kreal.shape[0]
     squeeze = x.ndim == 1
     if squeeze:
         x = x[None]
@@ -229,14 +289,16 @@ def bottomup_scan_op(col: jax.Array, val: jax.Array | None, x: jax.Array,
             skip = skip[None]
     with obs.phase(obs.ELL):
         with obs.phase("bsp.gather"):
-            g, val_t, bv, bk = _ell_gather(col, val, x, block_v,
-                                           _ell.SEMIRINGS[semiring][2])
+            g, val, widths = _sliced(col, val, x, widths,
+                                     _ell.SEMIRINGS[semiring][2])
         _record("bottomup_scan", interpret)
         with obs.phase("bsp.reduce"):
-            krealp = _pad_to(kreal.astype(jnp.int32), bv, 0)[None]
+            lanes = len(widths) * g.shape[2]
+            krealp = _pad_to(kreal.astype(jnp.int32), lanes, 0)[None]
             y, scanned = _bu.bottomup_scan(
-                g, val_t, krealp, semiring=semiring, early_exit=early_exit,
-                block_v=bv, block_k=bk, interpret=interpret)
+                *step_tables(widths), g, val, krealp, semiring=semiring,
+                early_exit=early_exit, chunks=len(widths),
+                interpret=interpret)
             y, scanned = y[:, :v], scanned[:, :v]
         if skip is not None and early_exit:
             scanned = jnp.where(skip, 0, scanned)

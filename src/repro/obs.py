@@ -1,6 +1,6 @@
 """Names for what the engine does, on the profiler's clock.
 
-Two kinds of names, one vocabulary each:
+Three kinds of names, one vocabulary each:
 
 - **Device phases** (``phase(name)``): a ``jax.named_scope`` around the
   code of one phase of the superstep.  A scope only writes the HLO
@@ -18,8 +18,12 @@ Two kinds of names, one vocabulary each:
   that also adds its call count, its total seconds and its self seconds
   (total less the time of the spans nested in it, on the same thread) to
   one in-memory table keyed by name.  ``snapshot()`` copies the table.
+- **Counters** (``count(name, **amounts)``): host-side sums of what the
+  program built, such as the sliced ELL's gathered slots
+  (:data:`ELL_PACK`); ``snapshot()`` shows each beside the spans, as its
+  call count and each amount's sum.
 
-Both are always on: there is no switch.  With no profiler running a span
+All are always on: there is no switch.  With no profiler running a span
 costs a clock read and a lock, and a phase nothing at run time.
 """
 from __future__ import annotations
@@ -50,8 +54,14 @@ FETCH = "repro.fetch"             # a result back in global vertex order
 HYBRID_SPLIT = "repro.hybrid.split"   # one degree split (BSPEngine)
 SPANS = (STATE_INIT, EXECUTE, WAIT, FETCH, HYBRID_SPLIT)
 
+# Counters.
+ELL_PACK = "repro.ell.pack"   # per split build: ELL slots gathered per
+                              # query and superstep, real ELL non-zeros
+COUNTERS = (ELL_PACK,)
+
 _lock = threading.Lock()
 _table: Dict[str, list] = {}       # name -> [count, total_s, self_s]
+_counters: Dict[str, Dict[str, float]] = {}   # name -> {"count", amounts}
 _local = threading.local()         # .stack: child seconds of open spans
 
 
@@ -89,9 +99,24 @@ def span(name: str):
             row[2] += total - children
 
 
+def count(name: str, **amounts: float) -> None:
+    """Add one call and each of ``amounts`` to the counter ``name``."""
+    if name not in COUNTERS:
+        raise ValueError(f"unknown counter {name!r}; counters are "
+                         f"{COUNTERS}")
+    with _lock:
+        row = _counters.setdefault(name, {"count": 0})
+        row["count"] += 1
+        for key, amount in amounts.items():
+            row[key] = row.get(key, 0) + amount
+
+
 def snapshot() -> Dict[str, Dict[str, float]]:
     """``{name: {"count", "total_s", "self_s"}}`` of every span so far in
-    this process: a copy, which later spans leave unchanged."""
+    this process, and ``{name: {"count", <amount>...}}`` of every counter:
+    a copy, which later spans and counts leave unchanged."""
     with _lock:
-        return {name: {"count": count, "total_s": total, "self_s": own}
-                for name, (count, total, own) in _table.items()}
+        out = {name: {"count": count, "total_s": total, "self_s": own}
+               for name, (count, total, own) in _table.items()}
+        out.update((name, dict(row)) for name, row in _counters.items())
+        return out

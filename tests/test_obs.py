@@ -18,9 +18,9 @@ from repro.core.bsp import BSPEngine, batch_state
 
 
 def _delta(before, after):
-    """Per-span change of the table between two snapshots."""
-    zero = {"count": 0, "total_s": 0.0, "self_s": 0.0}
-    return {name: {k: row[k] - before.get(name, zero)[k] for k in row}
+    """Per-span (and per-counter) change of the table between two
+    snapshots."""
+    return {name: {k: row[k] - before.get(name, {}).get(k, 0) for k in row}
             for name, row in after.items()
             if row != before.get(name)}
 
@@ -86,6 +86,16 @@ def test_self_time_ignores_spans_of_other_threads():
     assert not worker.is_alive()
     row = _delta(before, obs.snapshot())[obs.HYBRID_SPLIT]
     assert row["self_s"] == row["total_s"] > 0
+
+
+def test_counters_sum_their_amounts():
+    before = obs.snapshot()
+    obs.count(obs.ELL_PACK, slots=40, nonzeros=16)
+    obs.count(obs.ELL_PACK, slots=24, nonzeros=16)
+    assert _delta(before, obs.snapshot())[obs.ELL_PACK] == {
+        "count": 2, "slots": 64, "nonzeros": 32}
+    with pytest.raises(ValueError, match="unknown counter"):
+        obs.count("repro.nothing", slots=1)
 
 
 def test_snapshot_is_a_copy():

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -98,36 +99,60 @@ def test_outbox_reduce_compiles(one_chip, combine, q):
         one_chip, ((q, OUTBOX_X_PAD), F32), edge, edge, edge)
 
 
+def _sliced_compiles(sharding, kernel, semiring, q, widths):
+    """Compile ``kernel`` (``ell_spmv`` or ``bottomup_scan``) over a sliced
+    ELL of chunk ``widths``: the two step tables, the gathered
+    ``[q, Σ w_c, 512]`` values, the edge values unless ``min``, and the
+    bottom-up scan's per-row slot counts."""
+    chunks, slots = len(widths), int(widths.sum())
+    table = ((slots // ell_spmv.SLOTS,), I32)
+    shapes = [table, table, ((q, slots, 512), F32)]
+    with_val = semiring != "min"
+    if with_val:
+        shapes.append(((slots, 512), F32))
+    kw = dict(semiring=semiring, chunks=chunks)
+    if kernel is bottomup.bottomup_scan:
+        shapes.append(((1, chunks * 512), I32))
+        kw["early_exit"] = True
+
+    def fn(a, b, g, *rest):
+        val = rest[0] if with_val else None
+        return kernel(a, b, g, val, *rest[with_val:], **kw)
+
+    _compile(fn, sharding, *shapes)
+
+
+# chip_smoke's rectangular ELL: every chunk at the full width ELL_K.
+RECT_WIDTHS = np.full(ELL_V // 512, ELL_K, np.int32)
+# uniform-s20's sliced ELL: 2**20 rows in 2,048 chunks, rows longest
+# first, so the widths fall from 40 to 8 (Σ w_c ≈ 42.5 K slot rows).
+SLICED_WIDTHS = np.repeat(np.array([40, 32, 24, 16, 8], np.int32),
+                          [16, 200, 900, 800, 132])
+
+
 @pytest.mark.parametrize("semiring", ["plus_times", "min_plus", "min"])
 def test_ell_spmv_compiles(one_chip, semiring):
     q = 1 if semiring == "plus_times" else BFS_Q
-    bk = ell_spmv.slot_block(q, ELL_K, 512)
-    if semiring == "min":
-        fn = lambda g: ell_spmv.ell_spmv(  # noqa: E731
-            g, None, semiring=semiring, block_v=512, block_k=bk)
-        _compile(fn, one_chip, ((q, ELL_K, ELL_V), F32))
-    else:
-        fn = lambda g, v: ell_spmv.ell_spmv(  # noqa: E731
-            g, v, semiring=semiring, block_v=512, block_k=bk)
-        _compile(fn, one_chip, ((q, ELL_K, ELL_V), F32),
-                 ((ELL_K, ELL_V), F32))
+    _sliced_compiles(one_chip, ell_spmv.ell_spmv, semiring, q, RECT_WIDTHS)
 
 
 @pytest.mark.parametrize("semiring", ["min", "min_plus"])
 def test_bottomup_scan_compiles(one_chip, semiring):
-    bk = ell_spmv.slot_block(BFS_Q, ELL_K, 512)
-    g = ((BFS_Q, ELL_K, ELL_V), F32)
-    kreal = ((1, ELL_V), I32)
-    if semiring == "min":
-        fn = lambda g, k: bottomup.bottomup_scan(  # noqa: E731
-            g, None, k, semiring=semiring, early_exit=True, block_v=512,
-            block_k=bk)
-        _compile(fn, one_chip, g, kreal)
-    else:
-        fn = lambda g, v, k: bottomup.bottomup_scan(  # noqa: E731
-            g, v, k, semiring=semiring, early_exit=True, block_v=512,
-            block_k=bk)
-        _compile(fn, one_chip, g, ((ELL_K, ELL_V), F32), kreal)
+    _sliced_compiles(one_chip, bottomup.bottomup_scan, semiring, BFS_Q,
+                     RECT_WIDTHS)
+
+
+@pytest.mark.parametrize("semiring,q", [("plus_times", 1), ("min", 1),
+                                        ("min_plus", BFS_Q)])
+def test_sliced_ell_spmv_compiles(one_chip, semiring, q):
+    _sliced_compiles(one_chip, ell_spmv.ell_spmv, semiring, q,
+                     SLICED_WIDTHS)
+
+
+@pytest.mark.parametrize("semiring,q", [("min", 1), ("min_plus", BFS_Q)])
+def test_sliced_bottomup_scan_compiles(one_chip, semiring, q):
+    _sliced_compiles(one_chip, bottomup.bottomup_scan, semiring, q,
+                     SLICED_WIDTHS)
 
 
 @pytest.mark.parametrize("op", ["dense_spmv", "dense_spmv_minplus"])
