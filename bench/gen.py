@@ -8,11 +8,14 @@ program another input (other ids, partitions and layouts) of that work.
 Tuples are drawn with ``jax.random`` from the run's seed, on the device,
 in chunks of ``CHUNK_EDGES``, so the generator's device memory is the
 tuples themselves (8 bytes each, under the engine's resident graph) and a
-chunk's draws.  On the host they become the CSR arrays of
-``core.graph.CSRGraph`` in the canonical order ``from_edge_list`` gives,
-by one sort of packed 64-bit keys instead of a lexsort.  (A device sort of
-the 33.5 M entries of Graph500 scale 20 needs 536 MB, as much as the
-engine's own working set.)
+chunk's draws.  On a cell of several chips each chip draws its own run of
+chunks (``chunk_shares``); chunk ``i`` is keyed by its index alone, so the
+graph is the same to the bit whatever the chip count, and no chip holds
+more than ``ceil(chunks / chips)`` chunks of tuples.  On the host they
+become the CSR arrays of ``core.graph.CSRGraph`` in the canonical order
+``from_edge_list`` gives, by one sort of packed 64-bit keys instead of a
+lexsort.  (A device sort of the 33.5 M entries of Graph500 scale 20 needs
+536 MB, as much as the engine's own working set.)
 
 - ``kronecker``: the Graph500 v3 Kronecker generator
   (``kronecker_generator.m`` of the reference code): per edge and per bit
@@ -26,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -130,19 +133,20 @@ def _uniform_chunk(key, scale: int, chunk: int):
 
 @functools.partial(jax.jit, static_argnames=("kind", "scale", "num_tuples",
                                              "chunk"))
-def _tuples(key, labels_key, a, b, c, *, kind: str, scale: int,
+def _tuples(key, labels_key, first, a, b, c, *, kind: str, scale: int,
             num_tuples: int, chunk: int):
     """``num_tuples`` generated ``(src, dst)`` tuples of the structure drawn
-    from ``key``, in the ids ``labels_key`` gives, and those ids of the
-    structure's vertices.  One chunk is drawn per loop step and written in
-    place, so only a chunk's random draws are live beside the result."""
+    from ``key``, chunks ``first`` onwards, in the ids ``labels_key`` gives,
+    and those ids of the structure's vertices.  One chunk is drawn per loop
+    step and written in place, so only a chunk's random draws are live
+    beside the result."""
     k_edges, k_scramble = jax.random.split(key)
     val0, val1 = jax.random.bits(k_scramble, (2,), jnp.uint32)
     lab0, lab1 = jax.random.bits(labels_key, (2,), jnp.uint32)
 
     def body(i, carry):
         src, dst = carry
-        k = jax.random.fold_in(k_edges, i)
+        k = jax.random.fold_in(k_edges, first + i)
         if kind == "kronecker":
             s, d = _kronecker_chunk(k, scale, chunk, a, b, c)
             s = scramble(s, scale, val0, val1)
@@ -161,9 +165,17 @@ def _tuples(key, labels_key, a, b, c, *, kind: str, scale: int,
     return src, dst, ids
 
 
-def generate(spec: dict, seed: int) -> GeneratedGraph:
+def chunk_shares(chunks: int, parts: int) -> List[Tuple[int, int]]:
+    """``(first, count)`` of the chunks each of ``parts`` devices draws: runs
+    of ``ceil(chunks / parts)`` in order, the last ones shorter or empty."""
+    per = -(-chunks // parts)
+    return [(min(j * per, chunks), max(0, min(per, chunks - j * per)))
+            for j in range(parts)]
+
+
+def generate(spec: dict, seed: int, devices=None) -> GeneratedGraph:
     """The graph a configuration's ``graph`` block describes, its vertices
-    named by ``seed``.
+    named by ``seed``, drawn on ``devices`` (default: JAX's default device).
 
     ``spec`` keys: ``generator`` (``kronecker`` or ``uniform``),
     ``structure_seed``, ``scale``, ``edge_factor``, ``undirected``, and for
@@ -181,12 +193,21 @@ def generate(spec: dict, seed: int) -> GeneratedGraph:
         raise ValueError(f"{num_tuples} tuples are not a whole number of "
                          f"{chunk}-tuple chunks")
     structure_seed = int(spec["structure_seed"])
-    src, dst, ids = _tuples(
-        seed_key(structure_seed), seed_key(seed), float(spec.get("a", 0.0)),
-        float(spec.get("b", 0.0)), float(spec.get("c", 0.0)), kind=kind,
-        scale=scale, num_tuples=num_tuples, chunk=chunk)
-    src = np.asarray(src).astype(np.int64)
-    dst = np.asarray(dst).astype(np.int64)
+    keys = (seed_key(structure_seed), seed_key(seed))
+    abc = (float(spec.get("a", 0.0)), float(spec.get("b", 0.0)),
+           float(spec.get("c", 0.0)))
+    devices = list(devices or [None])
+    shares = chunk_shares(num_tuples // chunk, len(devices))
+    # Every device's program is dispatched before any result is read, so
+    # the devices draw at once.
+    drawn = [_tuples(*jax.device_put(keys, device), first, *abc, kind=kind,
+                     scale=scale, num_tuples=count * chunk, chunk=chunk)
+             for device, (first, count) in zip(devices, shares) if count]
+    src = np.concatenate([np.asarray(s) for s, _, _ in drawn])
+    dst = np.concatenate([np.asarray(d) for _, d, _ in drawn])
+    src, dst = src.astype(np.int64), dst.astype(np.int64)
+    ids = drawn[0][2]
+    del drawn
     undirected = bool(spec["undirected"])
     if undirected:
         src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])

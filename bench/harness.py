@@ -4,6 +4,10 @@ Everything a cell is made of is found by name under a root directory that
 holds ``BENCHMARK.json``:
 
 - the cell: an entry of ``workloads`` (``config``, ``traffic``, ``chips``);
+  its ``chips`` chooses the engine and the devices the graph is drawn on:
+  on one chip the program's ``BSPEngine``, on more its
+  ``DistributedBSPEngine`` over a mesh of that many chips (axis
+  ``parts``), each chip drawing its share of the generator's chunks;
 - its configuration: the JSON file the ``configs`` entry names (graph
   generator, partitioning, engine options);
 - its traffic mix: ``bench/traffic/<traffic>.json``, the parameters of
@@ -153,6 +157,10 @@ class Run:
     def queries(self) -> int:
         return int(self.traffic.get("queries", 1))
 
+    @property
+    def chips(self) -> int:
+        return int(self.workload["chips"])
+
 
 class CompileLog:
     """Counts JAX's compile requests and persistent-cache hits and misses
@@ -219,8 +227,8 @@ def devices_for(chips: int, require_tpu: bool):
     import jax
 
     devices = jax.devices()
-    if require_tpu and (devices[0].platform != "tpu"
-                        or len(devices) < chips):
+    if len(devices) < chips or (require_tpu
+                                and devices[0].platform != "tpu"):
         seen = collections.Counter(d.platform for d in devices)
         raise NoChip(
             f"this cell needs {chips} TPU chip(s); JAX sees "
@@ -230,11 +238,25 @@ def devices_for(chips: int, require_tpu: bool):
     return devices[:chips]
 
 
-def _peak_bytes(devices) -> Optional[int]:
+def _peak_bytes(devices) -> Optional[List[int]]:
+    """``peak_bytes_in_use`` of each device, or None where one has none."""
     stats = [d.memory_stats() for d in devices]
     if any(s is None or "peak_bytes_in_use" not in s for s in stats):
         return None
-    return max(int(s["peak_bytes_in_use"]) for s in stats)
+    return [int(s["peak_bytes_in_use"]) for s in stats]
+
+
+def build_engine(pg, config: dict, devices):
+    """The program's engine for ``config`` over ``devices``: ``BSPEngine``
+    on one, ``DistributedBSPEngine`` over a mesh of them on more."""
+    from repro.core.bsp import BSPEngine, DistributedBSPEngine
+
+    if len(devices) == 1:
+        return BSPEngine(pg, **config["engine"])
+    import jax
+
+    mesh = jax.make_mesh((len(devices),), ("parts",), devices=devices)
+    return DistributedBSPEngine(pg, mesh, **config["engine"])
 
 
 def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
@@ -255,7 +277,12 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     t_start = time.perf_counter() if t_start is None else t_start
     cell, config, traffic, layer = load_cell(root, workload)
     algorithm = load_algorithm(root, traffic)
-    devices = devices_for(int(cell["chips"]), require_tpu)
+    chips = int(cell["chips"])
+    part = config["partition"]
+    if int(part["parts"]) % chips:
+        raise BenchError(f"{workload}: {part['parts']} partitions do not "
+                         f"divide over {chips} chips")
+    devices = devices_for(chips, require_tpu)
     kind = devices[0].device_kind
     if expect_mosaic is None:
         expect_mosaic = devices[0].platform == "tpu"
@@ -263,25 +290,23 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
              if trace else {})
 
     from repro.core import partition as PT
-    from repro.core.bsp import BSPEngine
     from repro.core.graph import CSRGraph
     from repro.kernels.ops import KERNEL_PATHS
 
     stages: Dict[str, float] = {}
     with CompileLog() as setup_log:
         t = time.perf_counter()
-        gg = gen.generate(config["graph"], seed)
+        gg = gen.generate(config["graph"], seed, devices)
         stages["generate_s"] = time.perf_counter() - t
-        generate_peak = _peak_bytes(devices)
+        generate_peaks = _peak_bytes(devices)
 
         t = time.perf_counter()
-        part = config["partition"]
         pg = PT.partition(CSRGraph(gg.row_ptr, gg.col), int(part["parts"]),
                           part["strategy"], align=int(part.get("align", 8)))
         stages["partition_s"] = time.perf_counter() - t
 
         t = time.perf_counter()
-        engine = BSPEngine(pg, **config["engine"])
+        engine = build_engine(pg, config, devices)
         stages["engine_build_s"] = time.perf_counter() - t
 
         unit = algorithm.make_unit(engine, gg, traffic, seed, control)
@@ -291,11 +316,12 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
         stages["warmup_s"] = time.perf_counter() - t
     setup_s = time.perf_counter() - t_start
     log({"stage": "setup", "workload": workload, "seed": seed,
-         "setup_s": setup_s, **stages,
+         "chips": chips, "setup_s": setup_s, **stages,
          "compile_s": setup_log.seconds,
          "cache_hits": setup_log.hits, "cache_misses": setup_log.misses,
          "cache": "cold" if setup_log.misses else "warm",
-         "generate_peak_bytes": generate_peak,
+         "generate_peak_bytes": generate_peaks and max(generate_peaks),
+         "generate_peak_bytes_per_device": generate_peaks,
          "V": gg.num_vertices, "E": gg.num_edges})
 
     units: List[Unit] = []
@@ -318,7 +344,8 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
         finally:
             if trace_dir:
                 jax.profiler.stop_trace()
-    memory_peak = _peak_bytes(devices)
+    memory_peaks = _peak_bytes(devices)
+    memory_peak = memory_peaks and max(memory_peaks)
     engine_info = {"backend": engine.backend,
                    "k_dense": (engine.hybrid_plan() or {}).get("k_dense")}
     paths_after = collections.Counter(KERNEL_PATHS)
@@ -340,7 +367,8 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
         checks[name] <= limit for name, limit in limits.items())
 
     device = {"platform": devices[0].platform, "kind": kind,
-              "count": len(devices), "memory_peak_bytes": memory_peak}
+              "count": len(devices), "memory_peak_bytes": memory_peak,
+              "memory_peak_bytes_per_device": memory_peaks}
     result = {"correct": correct, "attempted": len(units),
               "failed": int(checks["units_failed"])}
     run = Run(workload=cell, config=config, traffic=traffic,

@@ -6,7 +6,9 @@ non-zero and one result per row, per query
 kernel's trace events times the HBM bandwidth, in percent.  With no dense
 block (``k_dense`` 0) the ELL holds every distinct edge and has one row per
 vertex; where the planner split off a dense block, or the trace holds no
-call, there is nothing this reader can count, and it returns None.
+call, there is nothing this reader can count, and it returns None.  So
+too on several chips, where each shard's ELL holds only the edges inside
+its shard.
 """
 from bench import roofline
 
@@ -14,7 +16,8 @@ KERNEL = "ell_spmv"
 
 
 def read(run):
-    if run.trace is None or run.engine.get("k_dense") != 0:
+    if (run.trace is None or run.chips > 1
+            or run.engine.get("k_dense") != 0):
         return None
     calls = run.trace.kernel_calls(KERNEL)
     seconds = run.trace.kernel_seconds(KERNEL)

@@ -2,7 +2,8 @@
 
 Least bytes of a whole-graph superstep (``roofline.superstep_least_bytes``
 on the graph's own V and E) times the supersteps the window's units ran,
-over the window's wall time times the chip's HBM bandwidth, in percent.
+over the window's wall time times the HBM bandwidth of the cell's chips
+(each holds its share of the graph), in percent.
 """
 from bench import roofline
 
@@ -14,4 +15,4 @@ def read(run):
     least = steps * roofline.superstep_least_bytes(
         run.num_vertices, run.num_edges, run.queries)
     return 100.0 * roofline.share(least, run.trace.window_s,
-                                  run.peaks["hbm_bytes_per_s"])
+                                  run.peaks["hbm_bytes_per_s"], run.chips)

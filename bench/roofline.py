@@ -40,8 +40,10 @@ def ell_spmv_least_bytes(nonzeros: int, rows: int, queries: int = 1) -> int:
     return WORD * (nonzeros + rows) * queries
 
 
-def share(least_bytes: float, seconds: float, bytes_per_s: float) -> float:
-    """``least_bytes`` moved in ``seconds`` as a fraction of the peak."""
+def share(least_bytes: float, seconds: float, bytes_per_s: float,
+          chips: int = 1) -> float:
+    """``least_bytes`` moved in ``seconds`` as a fraction of the peak of the
+    ``chips`` chips that share the work."""
     if seconds <= 0:
         raise ValueError(f"seconds must be > 0, got {seconds}")
-    return least_bytes / (seconds * bytes_per_s)
+    return least_bytes / (seconds * bytes_per_s * chips)

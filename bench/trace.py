@@ -10,14 +10,19 @@ kept free of the profiler so a test can drive it with a synthetic trace:
 - idle gaps: the window minus that union, each named by the benchmark span
   it falls in and the most specific host event that covers half of it;
 - kernel time: the summed durations of the device ops the kernel's name
-  names.  On a TPU an op event's name is its HLO instruction
+  names, and of that the exposed part: the time in which no other op runs
+  on the same device (a collective that compute does not hide).  On a TPU
+  an op event's name is its HLO instruction
   (``%ell_spmv.5 = f32[1,1048576]{1,0} custom-call(...)``), and a Pallas
-  kernel's instruction is named after the kernel.
+  kernel's instruction is named after the kernel; a v5e names the
+  exchange of ``DistributedBSPEngine`` ``%all_to_all.10 = ...
+  all-to-all(...)``.
 """
 from __future__ import annotations
 
 import dataclasses
 import glob
+import math
 import os
 import re
 from typing import Iterable, List, Optional, Sequence, Tuple
@@ -119,6 +124,14 @@ def name_gap(start: float, end: float, host: Sequence[Event]) -> str:
     return f"{span} / {what}"
 
 
+def _container(ev: Event) -> bool:
+    return any(c in ev.name for c in CONTAINERS)
+
+
+def _length(intervals: Sequence[Tuple[float, float]]) -> float:
+    return sum(end - start for start, end in intervals)
+
+
 def op_name(text: str) -> str:
     """``ell_spmv.5`` of ``%ell_spmv.5 = f32[...] custom-call(...)``."""
     return text.split(" = ", 1)[0].strip().lstrip("%")
@@ -146,8 +159,13 @@ class Summary:
     window_s: float
     busy_s: float                          # mean over the devices
     num_devices: int
-    ops: List[Event]                       # every op inside the window
+    device_ops: List[List[Event]]          # each device's ops in the window
     idle: List[Tuple[str, float]]          # longest gaps, named, seconds
+
+    @property
+    def ops(self) -> List[Event]:
+        """Every device's ops inside the window, in one list."""
+        return [e for ops in self.device_ops for e in ops]
 
     def kernel_seconds(self, kernel: str) -> float:
         """Device seconds of ``kernel``'s ops, mean over the devices."""
@@ -159,12 +177,26 @@ class Summary:
         return sum(1 for e in self.ops if matches(e, kernel)) \
             // self.num_devices
 
+    def exposed_seconds(self, kernel: str) -> float:
+        """Seconds inside ``kernel``'s ops in which no other op runs on the
+        same device, control flow left out, mean over the devices."""
+        total = 0.0
+        for ops in self.device_ops:
+            spans = [(e.start_ns, e.end_ns, matches(e, kernel)) for e in ops
+                     if not _container(e)]
+            other = [(start, end) for start, end, mine in spans if not mine]
+            # Exposed: what the kernel's ops add to the union of the rest.
+            total += _length(merge(((start, end) for start, end, _ in spans),
+                                   -math.inf, math.inf))
+            total -= _length(merge(other, -math.inf, math.inf))
+        return total / 1e9 / self.num_devices
+
     def top_ops(self, top: int = TOP) -> List[Tuple[str, float]]:
         """Ops by summed device seconds (over all devices), control flow
         left out."""
         totals: dict = {}
         for e in self.ops:
-            if any(c in e.name for c in CONTAINERS):
+            if _container(e):
                 continue
             key = short(e.name)
             totals[key] = totals.get(key, 0.0) + e.dur_ns / 1e9
@@ -177,19 +209,19 @@ def summarize(trace: Trace, lo: float, hi: float,
     ``[lo, hi)``."""
     if not trace.devices:
         raise ValueError("the trace holds no device operations")
-    busy_total, ops, idle = 0.0, [], []
+    busy_total, per_device, idle = 0.0, [], []
     for dev_ops in trace.devices:
         inside = [e for e in dev_ops if e.end_ns > lo and e.start_ns < hi]
-        ops.extend(inside)
+        per_device.append(inside)
         busy = merge(((e.start_ns, e.end_ns) for e in inside), lo, hi)
-        busy_total += sum(end - start for start, end in busy)
+        busy_total += _length(busy)
         idle.extend(gaps(busy, lo, hi))
     idle.sort(key=lambda gap: gap[0] - gap[1])
     named = [(name_gap(start, end, trace.host), (end - start) / 1e9)
              for start, end in idle[:top]]
     n = len(trace.devices)
     return Summary(window_s=(hi - lo) / 1e9, busy_s=busy_total / n / 1e9,
-                   num_devices=n, ops=ops, idle=named)
+                   num_devices=n, device_ops=per_device, idle=named)
 
 
 def window_of(trace: Trace, span: str) -> Optional[Tuple[float, float]]:

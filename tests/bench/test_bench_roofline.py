@@ -76,3 +76,44 @@ def test_recorded_shares_stay_at_or_below_one(what, least, seconds, expect):
                        roofline.peaks("TPU v5 lite")["hbm_bytes_per_s"])
     assert s == pytest.approx(expect, rel=0.01)
     assert s <= 1.0
+
+
+def _run_on(chips, trace):
+    from bench import harness
+
+    unit = harness.Unit(keys=[0], start=0.0, end=2.0, supersteps=7,
+                        edges=0, answers=None)
+    return harness.Run(
+        workload={"name": "w", "chips": chips}, config={}, traffic={},
+        stages={}, num_vertices=1 << 20, num_edges=33_554_432,
+        distinct_edges=16_777_216, units=[unit],
+        peaks={"hbm_bytes_per_s": 819e9}, engine={"k_dense": 0},
+        trace=trace)
+
+
+def test_step_share_divides_over_the_cells_chips():
+    from bench import harness, trace as tr
+
+    read = harness.load_metric(harness.ROOT, "step_hbm_share")
+    summary = tr.Summary(window_s=2.0, busy_s=2.0, num_devices=1,
+                         device_ops=[[]], idle=[])
+    one, four = read(_run_on(1, summary)), read(_run_on(4, summary))
+    assert one == pytest.approx(100 * 7 * roofline.superstep_least_bytes(
+        1 << 20, 33_554_432) / (2.0 * 819e9))
+    assert four == pytest.approx(one / 4)
+    assert roofline.share(819e9, 1.0, 819e9, chips=4) == 0.25
+
+
+def test_ell_roofline_is_silent_on_several_chips():
+    # Each shard's ELL holds only its own edges: the whole graph's count
+    # would read too high there.
+    from bench import harness, trace as tr
+
+    read = harness.load_metric(harness.ROOT, "ell_spmv_roofline")
+    call = tr.Event("%ell_spmv.5 = f32[1,8] custom-call()", 0.0, 1e6)
+    summary = tr.Summary(window_s=2.0, busy_s=2.0, num_devices=1,
+                         device_ops=[[call]], idle=[])
+    assert read(_run_on(1, summary)) == pytest.approx(
+        100 * roofline.ell_spmv_least_bytes(16_777_216, 1 << 20)
+        / (1e-3 * 819e9))
+    assert read(_run_on(4, summary)) is None

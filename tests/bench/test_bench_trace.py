@@ -90,3 +90,40 @@ def test_busy_is_averaged_over_devices(synthetic):
     s = tr.summarize(two, 0, 100)
     assert s.busy_s == pytest.approx((70.5e-9 + 100e-9) / 2)
     assert s.kernel_seconds("ell_spmv") == pytest.approx(15e-9)
+
+
+# As a v5e names the engine's exchange.
+A2A = "%all_to_all.10 = f32[1,1,1,4,1,8]{5,4,3,2,1,0} " \
+      "all-to-all(f32[1,1,1,4,1,8]{5,4,3,2,1,0} %o), channel_id=1"
+LOOP = "%while.1 = (s32[], f32[8]{0}) while((s32[], f32[8]) %t)"
+
+
+@pytest.mark.parametrize("others, exposed", [
+    ([(5, 25)], 0),                 # fully hidden under a fusion
+    ([(15, 30)], 5),                # [10, 15) runs alone
+    ([(30, 40)], 10),               # fully exposed
+    ([(0, 12), (18, 40)], 6),       # hidden at both ends
+], ids=["hidden", "partly", "exposed", "both-ends"])
+def test_exposed_collective_time(others, exposed):
+    # The all-to-all runs [10, 20) inside a loop that spans everything;
+    # the loop hides nothing, the other ops do.
+    ops = [ev(LOOP, 0, 50), ev(A2A, 10, 20)]
+    ops += [ev(f"%fusion.{i} = f32[8]{{0}} fusion(f32[8]{{0}} %p)", *span)
+            for i, span in enumerate(others)]
+    s = tr.summarize(tr.Trace(devices=[ops], host=[]), 0, 50)
+    assert s.kernel_seconds("all_to_all") == pytest.approx(10e-9)
+    assert s.exposed_seconds("all_to_all") == pytest.approx(exposed * 1e-9)
+
+
+def test_exposed_time_per_device_and_overlapping_calls():
+    # Device 0: two overlapping all-to-alls [10, 20) and [15, 25), nothing
+    # else: 15 exposed.  Device 1: one all-to-all hidden under a fusion.
+    dev0 = [ev(LOOP, 0, 50), ev(A2A, 10, 20), ev(A2A, 15, 25)]
+    dev1 = [ev(LOOP, 0, 50), ev(A2A, 10, 20),
+            ev("%fusion.2 = f32[8]{0} fusion(f32[8]{0} %p)", 0, 30)]
+    s = tr.summarize(tr.Trace(devices=[dev0, dev1], host=[]), 0, 50)
+    assert [len(ops) for ops in s.device_ops] == [3, 3]
+    assert len(s.ops) == 6
+    assert s.exposed_seconds("all_to_all") == pytest.approx(15e-9 / 2)
+    assert s.kernel_seconds("all_to_all") == pytest.approx(30e-9 / 2)
+    assert s.exposed_seconds("all-reduce") == 0
